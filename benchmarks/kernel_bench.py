@@ -170,8 +170,8 @@ def _reuse_rows(rows, rng):
     for bits, mode in ((8, "affine"), (4, "codebook")):
         tag = f"{mode}{bits}"
         qt = quantize(w, QuantConfig(bits, mode, "per_channel"))
-        levels, fold = rc_alphabet(bits, mode)
-        _, _, bn, _ = ops.pick_blocks(m, k, n, reuse_levels=len(levels))
+        _, fold = rc_alphabet(bits, mode)
+        _, _, bn, _ = ops.pick_blocks(m, k, n)
         # pass the QTensor, not qt.codes: int4 codes are packed two-per-
         # byte and the analytics must see decoded signed codes
         predicted = reuse_rate(qt, segment=bn, fold_sign=fold)

@@ -22,10 +22,16 @@ from repro.kernels import ops
 Array = Any
 
 
-def linear(x: Array, w, *, impl: str = "auto", out_dtype=None) -> Array:
-    """x @ w where w is an Array (dense path) or QTensor (AxLLM path)."""
+def linear(x: Array, w, *, impl: str = "auto", out_dtype=None,
+           row_parallel: bool = False) -> Array:
+    """x @ w where w is an Array (dense path) or QTensor (AxLLM path).
+
+    ``row_parallel`` marks the block-output projections (wo, down) that
+    tensor-parallel serving shards along their contraction dim; it only
+    steers how a kernel runs per shard under a mesh."""
     if isinstance(w, QTensor):
-        return ops.axllm_matmul(x, w, impl=impl, out_dtype=out_dtype)
+        return ops.axllm_matmul(x, w, impl=impl, out_dtype=out_dtype,
+                                row_parallel=row_parallel)
     y = jnp.dot(x, w.astype(x.dtype))
     return y if out_dtype is None else y.astype(out_dtype)
 

@@ -18,7 +18,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -53,4 +53,4 @@ def make_pipelined_apply(stage_fn: Callable, mesh, n_micro: int,
         return jax.lax.psum(outs, axis)
 
     return shard_map(pipelined, mesh=mesh, in_specs=(P(axis), P()),
-                     out_specs=P(), check_rep=False)
+                     out_specs=P(), check_vma=False)

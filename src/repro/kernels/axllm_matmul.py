@@ -1,10 +1,11 @@
 """Pallas TPU kernel: fused AxLLM dequant-matmul.
 
 TPU mapping of the paper's Result Cache (DESIGN.md §2): weights live in HBM as
-q-bit codes; the 2^q-entry codebook (the RC) is resident in VMEM for the whole
-kernel invocation and every weight tile is dequantized *in VMEM* right before
-the MXU contraction — the product of an input element with each unique value
-is materialized once per tile in registers/VMEM, never re-fetched from HBM.
+q-bit codes; the 2^q-entry codebook (the RC) is resident in SMEM for the whole
+kernel invocation and every weight tile is decoded *in VMEM* right before
+the MXU contraction — never re-fetched from HBM. Affine codes are integers,
+exact in bf16, so they meet bf16 activations on the MXU as they are and the
+per-channel scale is applied once to the f32 accumulator.
 The HBM traffic is `bytes(int8 codes) = N·M` instead of `2·N·M` (bf16) or
 `4·N·M` (f32); for int4-codebook mode it is `N·M/2` plus a 16-float table.
 
@@ -32,8 +33,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 DEFAULT_BLOCKS = (128, 512, 256)  # (bm, bk, bn)
 
 # Decode-shape M blocks, preferred order. Serving batches are small
@@ -44,42 +43,68 @@ DEFAULT_BLOCKS = (128, 512, 256)  # (bm, bk, bn)
 SKINNY_BM = (64, 32, 16, 8)
 
 
-def _dequant_tile(codes, scale_tile, codebook, bits: int, group_size: int):
-    """codes [bk, bn] int -> w f32 [bk, bn], inside the kernel (VMEM)."""
-    if codebook is None:
-        w = codes.astype(jnp.float32)
-        if scale_tile.ndim == 2 and scale_tile.shape[0] > 1:
-            # per-group: scale [bk/g, bn] -> broadcast over rows within group
-            g = group_size
-            bk, bn = codes.shape
-            w = w.reshape(bk // g, g, bn) * scale_tile[:, None, :]
-            return w.reshape(bk, bn)
-        return w * scale_tile  # per-channel [1, bn]
-    # codebook mode: 2^bits-entry RC lookup as a one-hot MXU contraction
-    # (16-entry for int4 — 6% FLOP overhead at bn=256; the gather-free form
-    # TPUs prefer). codes are recentred to [0, 2^bits).
-    n_levels = 1 << bits
-    offset = 1 << (bits - 1)
-    onehot = jax.nn.one_hot(codes + offset, n_levels, dtype=jnp.float32)
-    w = jax.lax.dot_general(
-        onehot, codebook.astype(jnp.float32),
-        (((2,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    return w * scale_tile
-
-
 def _unpack_nibbles(packed):
-    """uint8 [bk, bn/2] -> int8-valued int32 [bk, bn] in [-8, 7]."""
-    lo = (packed & 0xF).astype(jnp.int32)
-    hi = ((packed >> 4) & 0xF).astype(jnp.int32)
+    """uint8 [bk, bn/2] -> int32 [bk, bn] codes in [-8, 7], in *split*
+    column order: the low nibbles (the tile's even columns) then the high
+    nibbles (its odd columns). A lane concatenation instead of an
+    interleave keeps the unpack within what Mosaic lowers; the wrappers put
+    scales into that order with :func:`split_columns` and restore the
+    output with :func:`merge_columns`."""
+    p = packed.astype(jnp.int32)
+    lo = p & 0xF
+    hi = (p >> 4) & 0xF
     lo = jnp.where(lo >= 8, lo - 16, lo)
     hi = jnp.where(hi >= 8, hi - 16, hi)
-    bk, half = packed.shape
-    return jnp.stack([lo, hi], axis=-1).reshape(bk, half * 2)
+    return jnp.concatenate([lo, hi], axis=1)
 
 
-def _axllm_kernel(x_ref, codes_ref, scale_ref, cb_ref, out_ref, acc_ref, *,
-                  bits: int, packed: bool, group_size: int, n_k: int):
+def split_columns(a, bn: int):
+    """[..., N] natural column order -> per-bn-tile split order (even
+    columns of each tile, then its odd columns) — see _unpack_nibbles."""
+    *lead, n = a.shape
+    return a.reshape(*lead, n // bn, bn // 2, 2).swapaxes(-1, -2) \
+        .reshape(*lead, n)
+
+
+def merge_columns(a, bn: int):
+    """Inverse of :func:`split_columns`."""
+    *lead, n = a.shape
+    return a.reshape(*lead, n // bn, 2, bn // 2).swapaxes(-1, -2) \
+        .reshape(*lead, n)
+
+
+def _codebook_values(codes, cb_ref, n_levels: int):
+    """codebook[codes + n_levels/2] as f32, reading the table from SMEM:
+    one compare-select per level (the RC lookup without a gather)."""
+    offset = n_levels // 2
+
+    def level(i, w):
+        return jnp.where(codes == i - offset, cb_ref[i], w)
+
+    return jax.lax.fori_loop(0, n_levels, level,
+                             jnp.zeros(codes.shape, jnp.float32),
+                             unroll=n_levels <= 16)
+
+
+def _dot(x, w):
+    """x [bm, bk] @ w [bk, bn] -> f32. Integer-valued weights go to the MXU
+    in the activation dtype (int8/int4 codes are exact in bf16); anything
+    else contracts in f32 at full precision."""
+    if x.dtype == jnp.bfloat16 and w.dtype == jnp.int32:
+        return jax.lax.dot(x, w.astype(jnp.bfloat16),
+                           preferred_element_type=jnp.float32)
+    return jax.lax.dot(x.astype(jnp.float32), w.astype(jnp.float32),
+                       precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
+
+
+def _axllm_kernel(x_ref, codes_ref, scale_ref, *rest, bits: int,
+                  packed: bool, codebook: bool, per_group: bool,
+                  group_size: int, n_k: int):
+    if codebook:
+        cb_ref, out_ref, acc_ref = rest
+    else:
+        out_ref, acc_ref = rest
     k = pl.program_id(2)
 
     @pl.when(k == 0)
@@ -87,16 +112,20 @@ def _axllm_kernel(x_ref, codes_ref, scale_ref, cb_ref, out_ref, acc_ref, *,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     codes = codes_ref[...]
-    if packed:
-        codes = _unpack_nibbles(codes)
-    cb = cb_ref[...] if cb_ref is not None else None
-    w = _dequant_tile(codes, scale_ref[...], cb, bits, group_size)
-    x = x_ref[...].astype(jnp.float32)
-    acc_ref[...] += jax.lax.dot(x, w, preferred_element_type=jnp.float32)
+    codes = _unpack_nibbles(codes) if packed else codes.astype(jnp.int32)
+    w = _codebook_values(codes, cb_ref, 1 << bits) if codebook else codes
+    if per_group:
+        # scale [bk/g, bn] varies along K: dequantize before the contraction
+        bk, bn = w.shape
+        w = (w.astype(jnp.float32).reshape(bk // group_size, group_size, bn)
+             * scale_ref[...][:, None, :]).reshape(bk, bn)
+    acc_ref[...] += _dot(x_ref[...], w)
 
     @pl.when(k == n_k - 1)
     def _flush():
-        out_ref[...] = acc_ref[...]
+        # per-channel scales are constant along K: applied once, here
+        out_ref[...] = acc_ref[...] if per_group \
+            else acc_ref[...] * scale_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -126,6 +155,9 @@ def axllm_matmul_pallas(x: jax.Array, codes: jax.Array, scale: jax.Array,
     if per_group and bk % group_size:
         raise ValueError("per_group requires group_size | bk")
 
+    if packed:
+        # the kernel sees each tile's columns in split order (even, odd)
+        scale = split_columns(scale, bn)
     x_spec = pl.BlockSpec((bm, bk), lambda i, j, k: (i, k))
     if packed:
         codes_spec = pl.BlockSpec((bk, bn // 2), lambda i, j, k: (k, j))
@@ -141,27 +173,23 @@ def axllm_matmul_pallas(x: jax.Array, codes: jax.Array, scale: jax.Array,
     in_specs = [x_spec, codes_spec, scale_spec]
     args = [x, codes, scale]
     if codebook is not None:
-        in_specs.append(pl.BlockSpec((1 << bits,), lambda i, j, k: (0,)))
-        args.append(codebook)
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+        args.append(codebook.astype(jnp.float32))
 
     kernel = functools.partial(
-        _axllm_kernel if codebook is not None else _axllm_kernel_nocb,
-        bits=bits, packed=packed, group_size=group_size, n_k=n_k)
+        _axllm_kernel, bits=bits, packed=packed,
+        codebook=codebook is not None, per_group=per_group,
+        group_size=group_size, n_k=n_k)
 
-    return pl.pallas_call(
+    y = pl.pallas_call(
         kernel,
         grid=(m // bm, n // bn, n_k),
         in_specs=in_specs,
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*args)
-
-
-def _axllm_kernel_nocb(x_ref, codes_ref, scale_ref, out_ref, acc_ref, *,
-                       bits: int, packed: bool, group_size: int, n_k: int):
-    _axllm_kernel(x_ref, codes_ref, scale_ref, None, out_ref, acc_ref,
-                  bits=bits, packed=packed, group_size=group_size, n_k=n_k)
+    return merge_columns(y, bn) if packed else y

@@ -23,12 +23,13 @@ multiples, and flattening leading batch dims.
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core.quantization import QTensor
+from repro.dist import sharding as shd
 from repro.kernels import ref as _ref
 from repro.kernels import axllm_matmul as _amm
 from repro.kernels import reuse_matmul as _rmm
@@ -70,6 +71,87 @@ def _interpret(impl: str) -> bool:
 
 
 # ---------------------------------------------------------------------------
+# Kernels under a mesh
+# ---------------------------------------------------------------------------
+# GSPMD cannot partition a Mosaic custom call, so while a sharding context is
+# active (tensor-parallel serving, dist.sharding.activate) every Pallas call
+# runs once per shard inside a shard_map on its local blocks. The in_specs
+# come from the same logical names and rules that placed the operands, so
+# XLA inserts no resharding on the serving layout; any other layout is
+# resharded to the specs, never computed wrong.
+
+def _per_shard(fn, args, in_specs, out_specs):
+    mesh, _ = shd._current()
+    return jax.shard_map(fn, mesh=mesh, in_specs=tuple(in_specs),
+                         out_specs=out_specs, check_vma=False)(*args)
+
+
+def _spec(shape, names):
+    mesh, rules = shd._current()
+    return shd.resolve_spec(shape, names, mesh, rules)
+
+
+def _attention_call(fn, q, kv, extra, q_names, kv_names, extra_names):
+    """fn(q, *kv, *extra) -> q-shaped output, per shard under a mesh.
+
+    ``kv`` operands (keys, values, their scales) share ``kv_names``;
+    ``extra`` operands (lengths, block tables) take ``extra_names``. Query
+    heads and KV heads shard together or not at all: the kernel maps query
+    head h to KV head h // rep on its local blocks."""
+    if shd._current() is None:
+        return fn(q, *kv, *extra)
+    qs = _spec(q.shape, q_names)
+    ks = _spec(kv[0].shape, kv_names)
+    hq, hk = q_names.index("heads"), kv_names.index("kv_heads")
+    if qs[hq] != ks[hk]:
+        qs = P(*(None if i == hq else e for i, e in enumerate(qs)))
+        ks = P(*(None if i == hk else e for i, e in enumerate(ks)))
+    specs = [qs] + [ks] * len(kv) + [_spec(a.shape, n)
+                                     for a, n in zip(extra, extra_names)]
+    return _per_shard(fn, (q, *kv, *extra), specs, qs)
+
+
+def _matmul_call(fn, x2, qt: QTensor, row_parallel: bool):
+    """fn(x2, qt) -> [m, N] f32, per shard under a mesh.
+
+    Column-parallel weights (codes [K, N] sharded on N) keep x whole and
+    return N-sharded output; row-parallel ones (``row_parallel``: wo/down,
+    see dist.sharding._param_names) take K-sharded x and all-reduce the
+    partial products. Layouts that do not divide run replicated."""
+    if shd._current() is None:
+        return fn(x2, qt)
+    codes, scale = qt.codes, qt.scale
+    if row_parallel:
+        cs = _spec(codes.shape, ("mlp", None))
+        ax = cs[0]
+        ss = P()
+        if qt.granularity == "per_group":
+            ss = _spec(scale.shape, ("mlp",) + (None,) * (scale.ndim - 1))
+            if ss[0] != ax:
+                cs, ax, ss = P(), None, P()
+        xs, out_spec = P(None, ax), P()
+    else:
+        cs = _spec(codes.shape, (None, "mlp"))
+        ax = cs[1]
+        ss = P(*((None,) * (scale.ndim - 1)
+                 + ((ax,) if scale.shape[-1] > 1 else (None,))))
+        xs, out_spec = P(), P(None, ax)
+
+    def local(x_l, codes_l, scale_l):
+        n_l = qt.shape[-1] * codes_l.shape[-1] // codes.shape[-1]
+        qt_l = QTensor(codes=codes_l, scale=scale_l, codebook=qt.codebook,
+                       bits=qt.bits, mode=qt.mode,
+                       granularity=qt.granularity,
+                       group_size=qt.group_size, packed=qt.packed,
+                       shape=(codes_l.shape[0], n_l))
+        y = fn(x_l, qt_l)
+        return y if (not row_parallel or ax is None) \
+            else jax.lax.psum(y, ax)
+
+    return _per_shard(local, (x2, codes, scale), (xs, cs, ss), out_spec)
+
+
+# ---------------------------------------------------------------------------
 # AxLLM quantized matmul
 # ---------------------------------------------------------------------------
 
@@ -97,9 +179,19 @@ def _divisor_block(dim: int, target: int) -> int:
     return dim
 
 
+def _lane_block(dim: int, target: int) -> int:
+    """Largest power-of-two block in [128, target] that divides dim, else
+    the whole dim: Mosaic takes a lane-dim block that is a multiple of 128
+    or spans the array (tensor-parallel shards such as 768 / 4 = 192 land
+    on the second case)."""
+    return next((b for b in (512, 256, 128) if b <= target and dim % b == 0),
+                dim)
+
+
 def pick_blocks(m: int, k: int, n: int, group_size: int = 128,
-                per_group: bool = False, reuse_levels: Optional[int] = None):
-    """Block-size table for the fused dequant-matmul: (bm, bk, bn, pad_m).
+                per_group: bool = False):
+    """Block-size table for the fused dequant-matmul and the reuse (LUT)
+    matmul: (bm, bk, bn, pad_m).
 
     The pad decision is part of the table: decode shapes (m < 128) pick the
     largest SKINNY_BM entry that divides m exactly, so m ∈ {8,16,...,64}
@@ -107,31 +199,21 @@ def pick_blocks(m: int, k: int, n: int, group_size: int = 128,
     silently re-padded on every call. Skinny launches widen bn to 512 (vs
     the 256 default) to keep the MXU fed from the N grid dimension — the
     per-tile VMEM footprint stays far under budget because the x tile
-    shrinks with bm.
-
-    ``reuse_levels`` switches to the reuse (LUT) kernel's table: its
-    per-tile product table and one-hot selector scale with the alphabet
-    size L, so bk is capped at ``REUSE_BK_LEVELS / L`` (per_group tiles
-    floor at one group — their selector tile may exceed the soft budget,
-    which the docstring of reuse_matmul.py accepts explicitly).
+    shrinks with bm. bk and bn are lane blocks (:func:`_lane_block`).
 
     >>> pick_blocks(16, 128, 256)       # skinny decode shape: no pad
     (16, 128, 256, 0)
     >>> pick_blocks(9, 128, 256)        # odd m falls back to bm=8 + pad
     (8, 128, 256, 7)
-    >>> pick_blocks(16, 512, 256, reuse_levels=128)   # LUT: bk capped at 64
-    (16, 64, 256, 0)
+    >>> pick_blocks(4, 768, 2048)       # repro-100m decode, 4 slots
+    (8, 256, 512, 4)
     """
     if m >= 128:
         bm = 128
     else:
         bm = next((b for b in _amm.SKINNY_BM if m % b == 0), 8)
-    bk = _divisor_block(k, 512)
-    bn = _divisor_block(n, 512 if bm <= 32 else 256)
-    if reuse_levels:
-        from repro.kernels.reuse_matmul import REUSE_BK_LEVELS
-        bk = _divisor_block(k, max(REUSE_BK_LEVELS // reuse_levels, 8))
-        bn = _divisor_block(n, 256)
+    bk = _lane_block(k, 512)
+    bn = _lane_block(n, 512 if bm <= 32 else 256)
     if per_group:
         g_bk = (bk // group_size) * group_size
         if g_bk <= 0 or k % g_bk:
@@ -141,43 +223,48 @@ def pick_blocks(m: int, k: int, n: int, group_size: int = 128,
 
 
 def axllm_matmul(x: jax.Array, qt: QTensor, *, impl: str = "auto",
-                 out_dtype=None) -> jax.Array:
+                 out_dtype=None, row_parallel: bool = False) -> jax.Array:
     """y = x @ deq(qt). x: [..., K]; qt: [K, N]. Returns [..., N].
 
     ``impl`` in ``REUSE_IMPLS`` routes through the reuse (LUT) kernel —
     same result, gather-instead-of-multiply arithmetic (see
     :func:`reuse_matmul` for the stats-bearing entry point).
+    ``row_parallel`` says the weight is placed contraction-sharded under a
+    mesh (see :func:`_matmul_call`); it changes no result.
     """
     out_dtype = out_dtype or x.dtype
     if impl in REUSE_IMPLS:
-        y, _ = reuse_matmul(x, qt, impl=impl, out_dtype=out_dtype)
+        y, _ = reuse_matmul(x, qt, impl=impl, out_dtype=out_dtype,
+                            row_parallel=row_parallel)
         return y
     if not _use_pallas(impl):
         lead = x.shape[:-1]
         y = _ref.axllm_matmul_ref(x.reshape(-1, x.shape[-1]), qt, out_dtype)
         return y.reshape(*lead, -1)
 
-    kdim, n = qt.shape[-2], qt.shape[-1]
-    lead = x.shape[:-1]
-    x2 = x.reshape(-1, kdim)
-    m = x2.shape[0]
-    per_group = qt.granularity == "per_group"
-    bm, bk, bn, pad_m = pick_blocks(m, kdim, n, qt.group_size, per_group)
-    if pad_m:
-        x2 = jnp.pad(x2, ((0, pad_m), (0, 0)))
-    scale = _kernel_scale(qt)
     from repro.core.quantization import resolve_codebook
-    y = _amm.axllm_matmul_pallas(
-        x2, qt.codes, scale, resolve_codebook(qt),
-        bits=qt.bits, packed=qt.packed, group_size=qt.group_size,
-        blocks=(bm, bk, bn), interpret=_interpret(impl))
-    if pad_m:
-        y = y[:m]
-    return y.reshape(*lead, n).astype(out_dtype)
+
+    def kernel(x2, qt):
+        kdim, n = qt.shape[-2], qt.shape[-1]
+        m = x2.shape[0]
+        bm, bk, bn, pad_m = pick_blocks(m, kdim, n, qt.group_size,
+                                        qt.granularity == "per_group")
+        if pad_m:
+            x2 = jnp.pad(x2, ((0, pad_m), (0, 0)))
+        y = _amm.axllm_matmul_pallas(
+            x2, qt.codes, _kernel_scale(qt), resolve_codebook(qt),
+            bits=qt.bits, packed=qt.packed, group_size=qt.group_size,
+            blocks=(bm, bk, bn), interpret=_interpret(impl))
+        return y[:m]
+
+    lead = x.shape[:-1]
+    y = _matmul_call(kernel, x.reshape(-1, qt.shape[-2]), qt, row_parallel)
+    return y.reshape(*lead, qt.shape[-1]).astype(out_dtype)
 
 
 def reuse_matmul(x: jax.Array, qt: QTensor, *, impl: str = "auto",
-                 out_dtype=None, with_stats: bool = False):
+                 out_dtype=None, with_stats: bool = False,
+                 row_parallel: bool = False):
     """Reuse (LUT) matmul: ``(y, mults)`` = x @ deq(qt) by gathering cached
     alphabet products instead of multiplying every code (paper §III.b).
 
@@ -190,6 +277,7 @@ def reuse_matmul(x: jax.Array, qt: QTensor, *, impl: str = "auto",
     int32 scalar on the kernel paths and a host int on the ref path;
     ``with_stats=False`` (the serving default) returns ``mults=None`` —
     the ref-path count needs concrete codes and must stay out of jit.
+    Counting is not available under a mesh.
 
     impl: "auto"/"reuse" -> kernel on TPU, jnp oracle otherwise;
     "reuse_interpret"/"pallas_interpret" -> kernel body in Python;
@@ -200,29 +288,38 @@ def reuse_matmul(x: jax.Array, qt: QTensor, *, impl: str = "auto",
     kdim, n = qt.shape[-2], qt.shape[-1]
     lead = x.shape[:-1]
     x2 = x.reshape(-1, kdim)
-    m = x2.shape[0]
-    levels, fold = rc_alphabet(qt.bits, qt.mode)
-    per_group = qt.granularity == "per_group"
-    bm, bk, bn, pad_m = pick_blocks(m, kdim, n, qt.group_size, per_group,
-                                    reuse_levels=len(levels))
 
     use_kernel = impl in ("pallas", "pallas_interpret", "reuse_interpret") \
         or (impl in ("auto", "reuse") and _on_tpu())
     if not use_kernel:
         y = _ref.reuse_matmul_ref(x2, qt, jnp.float32)
-        mults = _ref.reuse_mult_count(qt, bn) if with_stats else None
+        mults = None
+        if with_stats:
+            bn = pick_blocks(x2.shape[0], kdim, n, qt.group_size,
+                             qt.granularity == "per_group")[2]
+            mults = _ref.reuse_mult_count(qt, bn)
         return y.reshape(*lead, n).astype(out_dtype), mults
 
     interpret = impl in ("pallas_interpret", "reuse_interpret")
-    if pad_m:
-        x2 = jnp.pad(x2, ((0, pad_m), (0, 0)))
-    y, counts = _rmm.reuse_matmul_pallas(
-        x2, qt.codes, _kernel_scale(qt), jnp.asarray(levels),
-        packed=qt.packed, fold_sign=fold, group_size=qt.group_size,
-        blocks=(bm, bk, bn), interpret=interpret)
-    if pad_m:
-        y = y[:m]
-    mults = counts[0, 0] if with_stats else None
+    counts = []
+
+    def kernel(x2, qt):
+        levels, fold = rc_alphabet(qt.bits, qt.mode)
+        m = x2.shape[0]
+        bm, bk, bn, pad_m = pick_blocks(m, qt.shape[-2], qt.shape[-1],
+                                        qt.group_size,
+                                        qt.granularity == "per_group")
+        if pad_m:
+            x2 = jnp.pad(x2, ((0, pad_m), (0, 0)))
+        y, c = _rmm.reuse_matmul_pallas(
+            x2, qt.codes, _kernel_scale(qt), jnp.asarray(levels),
+            packed=qt.packed, fold_sign=fold, group_size=qt.group_size,
+            blocks=(bm, bk, bn), count=with_stats, interpret=interpret)
+        counts.append(c)
+        return y[:m]
+
+    y = _matmul_call(kernel, x2, qt, row_parallel)
+    mults = counts[0][0, 0] if with_stats else None
     return y.reshape(*lead, n).astype(out_dtype), mults
 
 
@@ -247,8 +344,11 @@ def flash_attention(q, k, v, *, causal: bool = True,
     impl = _base_impl(impl)
     if _use_pallas(impl):
         from repro.kernels import flash_attention as _fa
-        return _fa.flash_attention_pallas(
-            q, k, v, causal=causal, interpret=_interpret(impl))
+        fn = functools.partial(_fa.flash_attention_pallas, causal=causal,
+                               interpret=_interpret(impl))
+        return _attention_call(fn, q, (k, v), (),
+                               ("batch", None, "heads", None),
+                               ("batch", None, "kv_heads", None), ())
     # memory-safe oracle (chunked online softmax) once the full [B,H,Sq,Sk]
     # score tensor stops being trivially small
     if q.shape[1] * k.shape[1] > 1024 * 1024:
@@ -269,23 +369,39 @@ def decode_attention(q, k_cache, v_cache, length, *, k_scale=None,
     ever being materialized contiguously.
     """
     impl = _base_impl(impl)
-    if block_tables is not None:
-        if _use_pallas(impl):
-            from repro.kernels import paged_decode_attention as _pda
-            return _pda.paged_decode_attention_pallas(
+    if not _use_pallas(impl):
+        if block_tables is not None:
+            return _ref.paged_decode_attention_ref(
                 q, k_cache, v_cache, block_tables, length,
-                k_scale=k_scale, v_scale=v_scale,
-                interpret=_interpret(impl))
-        return _ref.paged_decode_attention_ref(
-            q, k_cache, v_cache, block_tables, length,
-            k_scale=k_scale, v_scale=v_scale)
-    if _use_pallas(impl):
-        from repro.kernels import decode_attention as _da
+                k_scale=k_scale, v_scale=v_scale)
+        return _ref.decode_attention_ref(q, k_cache, v_cache, length,
+                                         k_scale=k_scale, v_scale=v_scale)
+    kv = (k_cache, v_cache) if k_scale is None \
+        else (k_cache, v_cache, k_scale, v_scale)
+    q_names = ("batch", "heads", None)
+    interpret = _interpret(impl)
+    if block_tables is not None:
+        from repro.kernels import paged_decode_attention as _pda
+
+        def paged(q, k, v, *rest):
+            *sc, bt, ln = rest
+            return _pda.paged_decode_attention_pallas(
+                q, k, v, bt, ln, k_scale=sc[0] if sc else None,
+                v_scale=sc[1] if sc else None, interpret=interpret)
+
+        return _attention_call(paged, q, kv, (block_tables, length), q_names,
+                               (None, None, "kv_heads", None),
+                               (("batch", None), ("batch",)))
+    from repro.kernels import decode_attention as _da
+
+    def dense(q, k, v, *rest):
+        *sc, ln = rest
         return _da.decode_attention_pallas(
-            q, k_cache, v_cache, length, k_scale=k_scale, v_scale=v_scale,
-            interpret=_interpret(impl))
-    return _ref.decode_attention_ref(q, k_cache, v_cache, length,
-                                     k_scale=k_scale, v_scale=v_scale)
+            q, k, v, ln, k_scale=sc[0] if sc else None,
+            v_scale=sc[1] if sc else None, interpret=interpret)
+
+    return _attention_call(dense, q, kv, (length,), q_names,
+                           ("batch", None, "kv_heads", None), (("batch",),))
 
 
 def prefix_attention(q, k_prefix, v_prefix, prefix_len, k_suffix, v_suffix,

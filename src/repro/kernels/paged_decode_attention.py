@@ -7,15 +7,16 @@ analogue of the paper's Result Cache: identical prompt prefixes map to the
 *same* physical blocks, so their KV is computed once and reused by every
 request that shares them (see repro/serve/paged_cache.py). This kernel is
 the dense flash-decode kernel of ``decode_attention.py`` generalized to
-gather its KV tiles through that indirection.
+gather its KV tiles through that indirection, and shares its tile body.
 
-Grid: (B*H, n_blocks_per_seq). The block table and the per-row valid
-lengths ride in as scalar-prefetch operands, so each KV tile's DMA source
-address is computed from ``block_tables[b, ib]`` *before* the kernel body
-runs (pltpu.PrefetchScalarGridSpec) — the gather costs no extra pass over
-HBM. Online-softmax state lives in VMEM scratch across the block dimension,
-exactly as in the dense kernel; int8-KV per-(position, head) scales stream
-through the same block-table index map.
+Grid: (B, n_blocks_per_seq). The block table and the per-row valid lengths
+ride in as scalar-prefetch operands, so each KV tile's DMA source address is
+computed from ``block_tables[b, ib]`` *before* the kernel body runs
+(pltpu.PrefetchScalarGridSpec) — the gather costs no extra pass over HBM.
+Each step loads one [block, Hk·d] tile (the pool viewed as
+[n_blocks, block, Hk·d]) and serves all H query heads of the row from it;
+online-softmax state lives in VMEM scratch across the block dimension.
+int8-KV per-(position, head) scales stream through the same index map.
 """
 
 from __future__ import annotations
@@ -27,54 +28,38 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+from repro.kernels.decode_attention import (attend_tile, expand_queries,
+                                            flush_state, init_state,
+                                            own_heads, scratch_shapes)
 
 
-def _paged_decode_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, ks_ref,
-                         vs_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                         scale: float, bs: int, n_b: int, h: int,
+def _paged_decode_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, *rest,
+                         scale: float, bs: int, n_b: int, rep: int,
                          quantized: bool):
-    bh = pl.program_id(0)
-    ib = pl.program_id(1)
+    if quantized:
+        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
+    else:
+        ks_ref = vs_ref = None
+        o_ref, m_ref, l_ref, acc_ref = rest
+    b, ib = pl.program_id(0), pl.program_id(1)
+    length = len_ref[b]
 
     @pl.when(ib == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        init_state(m_ref, l_ref, acc_ref)
 
-    q = q_ref[...].astype(jnp.float32)                     # [1, d]
-    k = k_ref[0, :, 0, :].astype(jnp.float32)              # [bs, d]
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    if quantized:
-        k = k * ks_ref[0, :, 0, :].astype(jnp.float32)     # [bs, 1] scales
-        v = v * vs_ref[0, :, 0, :].astype(jnp.float32)
-
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    # global key position of this tile: block ib holds positions
-    # [ib*bs, (ib+1)*bs) of the row's logical sequence, wherever the
-    # block table placed them in the pool
-    kpos = ib * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-    valid = kpos < len_ref[bh // h]   # scalar-prefetch refs are unblocked
-    vmask = valid.astype(jnp.float32)
-    s = jnp.where(valid, s, NEG_INF)
-
-    m_prev = m_ref[:1, :1]
-    l_prev = l_ref[:1, :1]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new) * vmask
-    corr = jnp.exp(m_prev - m_new)
-    l_new = l_prev * corr + p.sum(axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot(
-        p, v, preferred_element_type=jnp.float32)
-    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+    # block ib holds positions [ib*bs, (ib+1)*bs) of the row's logical
+    # sequence, wherever the block table placed them in the pool; blocks
+    # wholly past the row's length are skipped
+    @pl.when(ib * bs < length)
+    def _tile():
+        attend_tile(q_ref, k_ref, v_ref, ks_ref, vs_ref, m_ref, l_ref,
+                    acc_ref, length=length, kpos0=ib * bs, scale=scale,
+                    rep=rep)
 
     @pl.when(ib == n_b - 1)
     def _flush():
-        o_ref[...] = (acc_ref[...] /
-                      jnp.maximum(l_ref[:1, :1], 1e-30)).astype(o_ref.dtype)
+        flush_state(o_ref, l_ref, acc_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -88,50 +73,36 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, block_tables, length, *,
     the length mask keeps them out of the softmax.
     """
     b, h, d = q.shape
-    bs, hk = k_pool.shape[1], k_pool.shape[2]
+    nb, bs, hk = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
     mb = block_tables.shape[1]
-    rep = h // hk
     quantized = k_scale is not None
 
-    qf = q.reshape(b * h, d)
-    if not quantized:
-        # dummy scale refs keep the kernel signature uniform (one trash
-        # block's worth per index — the map below pins them to block 0)
-        k_scale = jnp.ones((1, bs, hk, 1), jnp.float32)
-        v_scale = jnp.ones((1, bs, hk, 1), jnp.float32)
+    def kv_index(bi, ib, len_ref, bt_ref):
+        return (bt_ref[bi, ib], 0, 0)
 
-    def kv_index(bh, ib, len_ref, bt_ref):
-        return (bt_ref[bh // h, ib], 0, (bh % h) // rep, 0)
+    kv_spec = pl.BlockSpec((1, bs, hk * d), kv_index)
+    in_specs = [pl.BlockSpec((1, h, hk * d),
+                             lambda bi, ib, len_ref, bt_ref: (bi, 0, 0)),
+                kv_spec, kv_spec]
+    args = [expand_queries(q, hk), k_pool.reshape(nb, bs, hk * d),
+            v_pool.reshape(nb, bs, hk * d)]
+    if quantized:
+        in_specs += [pl.BlockSpec((1, bs, hk), kv_index)] * 2
+        args += [k_scale.reshape(nb, bs, hk), v_scale.reshape(nb, bs, hk)]
 
-    def scale_index(bh, ib, len_ref, bt_ref):
-        if quantized:
-            return kv_index(bh, ib, len_ref, bt_ref)
-        return (0, 0, (bh % h) // rep, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,          # lengths + block table in SMEM
-        grid=(b * h, mb),
-        in_specs=[
-            pl.BlockSpec((1, d), lambda bh, ib, len_ref, bt_ref: (bh, 0)),
-            pl.BlockSpec((1, bs, 1, d), kv_index),
-            pl.BlockSpec((1, bs, 1, d), kv_index),
-            pl.BlockSpec((1, bs, 1, 1), scale_index),
-            pl.BlockSpec((1, bs, 1, 1), scale_index),
-        ],
-        out_specs=pl.BlockSpec((1, d),
-                               lambda bh, ib, len_ref, bt_ref: (bh, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((8, 128), jnp.float32),
-            pltpu.VMEM((8, 128), jnp.float32),
-            pltpu.VMEM((1, d), jnp.float32),
-        ],
-    )
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, scale=1.0 / (d ** 0.5),
-                          bs=bs, n_b=mb, h=h, quantized=quantized),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b * h, d), q.dtype),
+                          bs=bs, n_b=mb, rep=h // hk, quantized=quantized),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,          # lengths + block table in SMEM
+            grid=(b, mb),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec(
+                (1, h, hk * d), lambda bi, ib, len_ref, bt_ref: (bi, 0, 0)),
+            scratch_shapes=scratch_shapes(h, hk * d)),
+        out_shape=jax.ShapeDtypeStruct((b, h, hk * d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(length.astype(jnp.int32), block_tables.astype(jnp.int32),
-      qf, k_pool, v_pool, k_scale, v_scale)
-    return out.reshape(b, h, d)
+    )(length.astype(jnp.int32), block_tables.astype(jnp.int32), *args)
+    return own_heads(out, hk).astype(q.dtype)

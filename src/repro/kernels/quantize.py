@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 MAX_K_VMEM = 8192
 
 
@@ -48,7 +46,7 @@ def quantize_pallas(w, *, bits: int = 8, block_n: int = 256,
                    pl.BlockSpec((1, bn), lambda j: (0, j))],
         out_shape=[jax.ShapeDtypeStruct((k, n), jnp.int8),
                    jax.ShapeDtypeStruct((1, n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(w)

@@ -26,7 +26,7 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 NEG_INF = -1e30
@@ -138,5 +138,5 @@ def decode_attention_seqsharded(q, k_cache, v_cache, new_k, new_v, pos,
         return out, k_l2, v_l2
 
     fn = shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                   out_specs=tuple(out_specs), check_rep=False)
+                   out_specs=tuple(out_specs), check_vma=False)
     return fn(*args)

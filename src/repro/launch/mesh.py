@@ -61,18 +61,28 @@ def make_serve_mesh(spec: str):
     return make_host_mesh(data=data, model=model)
 
 
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis Auto-typed: the sharding layer
+    places arrays with NamedShardings and ``with_sharding_constraint`` and
+    lets GSPMD propagate the rest, which Explicit axes (the installed JAX's
+    default) would turn into per-op sharding-type errors."""
+    from jax.sharding import AxisType
+    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 2, model: int = 2, pod: int = 1):
     """Small mesh over however many (host) devices exist — tests/examples."""
     if pod > 1:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return make_mesh((pod, data, model), ("pod", "data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def single_device_mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))

@@ -69,6 +69,7 @@ import jax
 import numpy as np
 
 from repro.configs import apply_overrides, get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import get_model
 from repro.serve.engine import ServeEngine
 from repro.train import checkpoint as C
@@ -225,6 +226,7 @@ def main(argv=None):
                     help="print scheduler stats JSON after the run")
     ap.add_argument("--set", action="append", default=[])
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     # mesh construction precedes the first jax computation: on CPU the
     # host-device forcing flag only takes effect before backend init
@@ -343,10 +345,11 @@ def main(argv=None):
     lora_tag = f", {eng.stats.lora_requests} LoRA requests" if args.lora \
         else ""
     mesh_tag = f", mesh {args.mesh_shape}" if mesh is not None else ""
+    devs = jax.devices()
     print(f"[{mode}] {len(reqs)} requests, {toks} tokens, "
           f"{toks/dt:.1f} tok/s, occupancy "
           f"{eng.stats.mean_occupancy:.2f}{lora_tag}{mesh_tag} "
-          f"(host fallback path)")
+          f"(device {devs[0].platform}/{devs[0].device_kind} x{len(devs)})")
     if args.arrival_rate:
         st = eng.stats
         print(f"  open-loop [{args.arrival_rate}, admission="

@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from repro.configs import apply_overrides, get_config
 from repro.data.pipeline import make_dataset, shard_batch
 from repro.dist import sharding as shd
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models.model import get_model
 from repro.optim import adamw
 from repro.train.fault_tolerance import StepMonitor, resilient_train
@@ -53,14 +55,15 @@ def build_mesh(spec: str):
         model = 1
         while model * 2 <= n and n % (model * 2) == 0 and model < 8:
             model *= 2
-        return jax.make_mesh((n // model, model), ("data", "model"))
+        return make_mesh((n // model, model), ("data", "model"))
     dims = tuple(int(x) for x in spec.split("x"))
     axes = {2: ("data", "model"), 3: ("pod", "data", "model")}[len(dims)]
-    return jax.make_mesh(dims, axes)
+    return make_mesh(dims, axes)
 
 
 def main(argv=None):
     args = parse_args(argv)
+    enable_compile_cache()
     if args.distributed:
         jax.distributed.initialize()
     cfg = get_config(args.arch)

@@ -200,12 +200,12 @@ def attention_fwd(p, x, cfg, *, positions=None, impl: str = "auto"):
     k = shard(k, "batch", "seq", "kv_heads")
     out = ops.flash_attention(q, k, v, causal=True, impl=impl)
     out = out.reshape(b, s, -1)
-    return linear(out, p["wo"], impl=impl)
+    return linear(out, p["wo"], impl=impl, row_parallel=True)
 
 
 def _wo_project(p, out, impl, adapters, adapter_idx, lora_scaling):
     """Output projection with an optional gathered LoRA delta on wo."""
-    y = linear(out, p["wo"], impl=impl)
+    y = linear(out, p["wo"], impl=impl, row_parallel=True)
     if adapters is not None and "wo" in adapters:
         y = y + lora_delta_batched(out, adapters["wo"], adapter_idx,
                                    lora_scaling).astype(y.dtype)

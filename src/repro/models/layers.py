@@ -112,7 +112,7 @@ def mlp_fwd(p, x, cfg, impl: str = "auto"):
     else:
         h = jax.nn.gelu(linear(x, p["up"], impl=impl))
     h = _shard(h, "batch", "seq", "mlp")
-    return linear(h, p["down"], impl=impl)
+    return linear(h, p["down"], impl=impl, row_parallel=True)
 
 
 # ---------------------------------------------------------------------------

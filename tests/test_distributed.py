@@ -16,12 +16,12 @@ def run_devices(body: str, n: int = 8):
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
-    # pin the backend: unset JAX_PLATFORMS makes jax probe for accelerator
-    # plugins, which hangs on CPU-only CI hosts; the forced host device
-    # count composes fine with an explicit cpu platform
+    # the forced device count applies to the host (CPU) backend: pin it, so
+    # a child never reaches for an accelerator its parent may hold
     env["JAX_PLATFORMS"] = "cpu"
-    script = "import jax, jax.numpy as jnp, numpy as np\n" + \
-        textwrap.dedent(body)
+    script = ("import jax, jax.numpy as jnp, numpy as np\n"
+              "from repro.launch.mesh import make_mesh\n"
+              + textwrap.dedent(body))
     r = subprocess.run([sys.executable, "-c", script], env=env,
                        capture_output=True, text=True, timeout=600)
     assert r.returncode == 0, f"STDOUT:\n{r.stdout}\nSTDERR:\n{r.stderr}"
@@ -37,7 +37,7 @@ def test_sharded_train_step_runs():
     from repro.optim import adamw
     from repro.train.loop import make_train_step
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg = ModelConfig(name="t", family="dense", n_layers=2, d_model=64,
                       n_heads=4, n_kv_heads=2, d_ff=128, vocab_size=256,
                       head_dim=16, vocab_pad_multiple=64, dtype="float32",
@@ -75,9 +75,9 @@ def test_elastic_checkpoint_reshard():
                       n_heads=4, n_kv_heads=2, d_ff=128, vocab_size=256,
                       head_dim=16, vocab_pad_multiple=64, dtype="float32")
     api = get_model(cfg)
-    mesh_a = jax.make_mesh((2, 2), ("data", "model"),
+    mesh_a = make_mesh((2, 2), ("data", "model"),
                            devices=jax.devices()[:4])
-    mesh_b = jax.make_mesh((4, 2), ("data", "model"))
+    mesh_b = make_mesh((4, 2), ("data", "model"))
     params = api.init(jax.random.PRNGKey(0))
     specs_a = shd.param_specs(params, mesh_a)
     params_a = jax.tree_util.tree_map(jax.device_put, params, specs_a)
@@ -99,11 +99,11 @@ def test_elastic_checkpoint_reshard():
 def test_compressed_allreduce():
     run_devices("""
     from functools import partial
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.dist.compression import compressed_allreduce_mean, wire_bytes
 
-    mesh = jax.make_mesh((8,), ("pod",))
+    mesh = make_mesh((8,), ("pod",))
     x = jax.random.normal(jax.random.PRNGKey(0), (8, 1024)) * \
         (1 + jnp.arange(8)[:, None]).astype(jnp.float32)
 
@@ -125,11 +125,11 @@ def test_compressed_allreduce():
 def test_error_feedback_reduces_bias():
     run_devices("""
     from functools import partial
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.dist.compression import compressed_allreduce_mean
 
-    mesh = jax.make_mesh((4,), ("pod",), devices=jax.devices()[:4])
+    mesh = make_mesh((4,), ("pod",), devices=jax.devices()[:4])
     g = jax.random.normal(jax.random.PRNGKey(1), (4, 512))
 
     @partial(shard_map, mesh=mesh, in_specs=(P("pod"), P("pod")),
@@ -158,12 +158,12 @@ def test_error_feedback_reduces_bias():
 def test_ring_collective_matmuls():
     run_devices("""
     from functools import partial
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.dist.collective_matmul import (ring_allgather_matmul,
                                               ring_matmul_reducescatter)
 
-    mesh = jax.make_mesh((8,), ("model",))
+    mesh = make_mesh((8,), ("model",))
     B, K, N = 16, 64, 32
     x = jax.random.normal(jax.random.PRNGKey(0), (B, K))
     w = jax.random.normal(jax.random.PRNGKey(1), (K, N))
@@ -194,7 +194,7 @@ def test_pipeline_parallel_matches_sequential():
     run_devices("""
     from repro.dist.pipeline import make_pipelined_apply
 
-    mesh = jax.make_mesh((4,), ("stage",), devices=jax.devices()[:4])
+    mesh = make_mesh((4,), ("stage",), devices=jax.devices()[:4])
     S, D = 4, 32
     ws = jax.random.normal(jax.random.PRNGKey(0), (S, D, D)) / jnp.sqrt(D)
 
@@ -225,7 +225,7 @@ def test_mini_production_mesh_compiles_multipod_shape():
     from repro.launch import shapes as shp
     from repro.launch.dryrun import build_cell
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     cfg = ModelConfig(name="t", family="dense", n_layers=2, d_model=64,
                       n_heads=4, n_kv_heads=2, d_ff=128, vocab_size=256,
                       head_dim=16, vocab_pad_multiple=64, grad_accum=2)
@@ -265,7 +265,7 @@ def test_seqsharded_decode_matches_reference():
     ld_ref, _ = api.decode(params, nxt, cache_ref)
 
     # sharded: mesh (2 data, 4 model); kv=2 -> cache seq shards over model
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     with shd.activate(mesh):
         cache2 = api.init_cache(4, 32)
         cspec = shd.cache_specs(jax.eval_shape(lambda: api.init_cache(4, 32)),

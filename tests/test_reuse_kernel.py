@@ -37,6 +37,7 @@ from repro.core import reuse as R
 from repro.core.quantization import (QTensor, QuantConfig, nf4_codebook,
                                      pack_int4, quantize)
 from repro.kernels import ops
+from repro.launch.mesh import make_mesh
 
 M, K, N = 64, 512, 256
 
@@ -207,7 +208,7 @@ def test_kernel_mult_count_matches_segment_unique_counts(case):
     qt = _qtensor(codes, np.full((1, N), 1.0, np.float32), bits, mode)
     x = jnp.ones((4, K), jnp.float32)
     levels, fold = R.rc_alphabet(bits, mode)
-    _, _, bn, _ = ops.pick_blocks(4, K, N, reuse_levels=len(levels))
+    _, _, bn, _ = ops.pick_blocks(4, K, N)
     expect = int(R.segment_unique_counts(codes, bn, fold_sign=fold).sum())
     _, m_ref = ops.reuse_matmul(x, qt, impl="reuse_ref", with_stats=True)
     _, m_ker = ops.reuse_matmul(x, qt, impl="reuse_interpret",
@@ -284,7 +285,7 @@ def test_codebook_counts_use_unfolded_cells():
     qt = _qtensor(codes, np.full((1, N), 1.0, np.float32), 4, "codebook")
     levels, fold = R.rc_alphabet(4, "codebook")
     assert fold is False
-    _, _, bn, _ = ops.pick_blocks(4, K, N, reuse_levels=len(levels))
+    _, _, bn, _ = ops.pick_blocks(4, K, N)
     unfolded = int(R.segment_unique_counts(codes, bn,
                                            fold_sign=False).sum())
     folded = int(R.segment_unique_counts(codes, bn, fold_sign=True).sum())
@@ -457,13 +458,12 @@ def test_ring_allgather_matmul_matches_reuse_bit_exact(
     sums < 2^24 * 2^-e), so the changed association cannot round."""
     from functools import partial
 
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.dist.collective_matmul import ring_allgather_matmul
 
-    mesh = jax.make_mesh((4,), ("model",),
-                         devices=eight_cpu_devices[:4])
+    mesh = make_mesh((4,), ("model",), devices=eight_cpu_devices[:4])
     rng, x = _int_x(3)
     codes = rng.integers(-127, 128, size=(K, N)).astype(np.int8)
     if gran == "per_group":
@@ -501,13 +501,12 @@ def test_ring_reducescatter_matmul_matches_reuse_bit_exact(
     matmul in the dyadic regime (per-shard partials are exact dyadics)."""
     from functools import partial
 
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.dist.collective_matmul import ring_matmul_reducescatter
 
-    mesh = jax.make_mesh((4,), ("model",),
-                         devices=eight_cpu_devices[:4])
+    mesh = make_mesh((4,), ("model",), devices=eight_cpu_devices[:4])
     rng, x = _int_x(4)
     codes = rng.integers(-127, 128, size=(K, N)).astype(np.int8)
     qt = _qtensor(codes, np.full((1, N), 127.0 * 2.0 ** -3, np.float32),

@@ -44,6 +44,10 @@ MODES = {
     "fused": dict(quantize=True, fuse_qkv=True),
     "lora": dict(quantize=True),
     "paged": dict(quantize=True, paged=True, kv_block_size=8),
+    # the Pallas kernels themselves, run per shard (kernels/ops.py)
+    "kernels": dict(quantize=True, impl="pallas_interpret"),
+    "kernels_paged": dict(quantize=True, impl="pallas_interpret",
+                          paged=True, kv_block_size=8),
 }
 
 
@@ -80,6 +84,13 @@ def _assert_token_identical(params, mode, model_size):
 # fast subset (tier-1): one head-sharded mode pair at mesh 2
 @pytest.mark.parametrize("mode", ["fp32", "int8"])
 def test_engine_token_identity_mesh2(base_params, mode):
+    _assert_token_identical(base_params, mode, 2)
+
+
+# Mosaic kernels cannot be partitioned by GSPMD: under a mesh each runs
+# once per shard in a shard_map, which must not change a token
+@pytest.mark.parametrize("mode", ["kernels", "kernels_paged"])
+def test_engine_kernels_token_identity_mesh2(base_params, mode):
     _assert_token_identical(base_params, mode, 2)
 
 
