@@ -159,8 +159,21 @@ Scheduler contract
   pool, published prefixes untouched). Requires `greedy=True` and an
   attention family; preempted speculating slots restore by recompute
   (the fast swap path would miss the draft cache).
+- **Tracing.** Every step, prefill and decode dispatch, host pull of
+  device results, pager plan, harvest and preemption opens a
+  `jax.profiler.TraceAnnotation` (`engine.step`, `engine.admit`,
+  `engine.prefill`, `engine.sync`, `engine.prefill_sync`, `engine.plan`,
+  `engine.decode`, `engine.emit`, `engine.preempt`) whose keyword stats
+  carry the work done (`rows`, `tokens`, `hit`, `steps`, `cow`) and the
+  request ids (`rids`, space-separated). Every jitted program is named
+  (`jit_prefill`, `jit_paged_prefill`, `jit_decode_chunk`,
+  `jit_copy_blocks`, `jit_write_wave`, `jit_sample_tokens`,
+  `jit_spec_draft`, `jit_spec_verify`). Without a profiler session a
+  span costs about a microsecond and records nothing.
 - **Stats.** `engine.stats` tracks admitted/finished/truncated requests,
-  decode steps/tokens, prefill waves/tokens/compiles (plus wall time),
+  queue wait (`queue_wait_s`, the sum of each request's `t_admit -
+  t_submit`), decode steps/tokens, prefill waves/tokens/compiles (plus
+  wall time),
   LoRA-carrying requests, mean slot occupancy, — in paged mode —
   `prefix_hit_tokens` / `blocks_in_use` / `cow_copies`, and — under
   speculation — drafted/accepted token counts with `acceptance_rate`
@@ -177,6 +190,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import math
 import time
 from typing import List, Optional
@@ -185,6 +199,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core.axllm_linear import deploy_quantize
 from repro.core.quantization import QuantConfig, derive_draft_params
@@ -248,6 +263,7 @@ class Request:
     finish_reason: Optional[str] = None   # eos / max_new / cache_full /
                                           # rejected / expired / cancelled
     t_submit: float = 0.0             # engine-clock submit time
+    t_admit: Optional[float] = None   # first seated (not a restore)
     t_first: Optional[float] = None   # first-token *emission* time (TTFT)
     t_last: Optional[float] = None    # last-token time (ITL base)
     preemptions: int = 0              # times swapped out of a slot
@@ -277,6 +293,7 @@ class EngineStats:
     prefill_tokens: int = 0
     prefill_compiles: int = 0
     prefill_wall_s: float = 0.0       # host wall time inside prefill waves
+    queue_wait_s: float = 0.0         # sum of t_admit - t_submit
     lora_requests: int = 0            # admitted requests carrying an adapter
     occupancy_sum: float = 0.0        # sum over steps of active/n_slots
     # paged-KV mode (prefix reuse): prompt tokens whose KV came from the
@@ -335,7 +352,7 @@ class EngineStats:
         return d
 
 
-def _sample_tokens(logits, rng, *, greedy: bool, vocab_size: int):
+def sample_tokens(logits, rng, *, greedy: bool, vocab_size: int):
     """On-device sampling: greedy/sampled is jit-static, and the sampled
     path threads a freshly split PRNG key per call instead of re-seeding
     from host state. Returns (tokens [B] int32, advanced key)."""
@@ -346,6 +363,37 @@ def _sample_tokens(logits, rng, *, greedy: bool, vocab_size: int):
         return jnp.argmax(logits, -1).astype(jnp.int32), rng
     rng, k = jax.random.split(rng)
     return jax.random.categorical(k, logits).astype(jnp.int32), rng
+
+
+def write_wave(cache, wave_cache, src, dst, *, cache_spec):
+    """Copy wave rows ``src`` into engine slots ``dst`` on each leaf's
+    declared batch axis (``api.cache_spec``)."""
+    def put(full, one, ax):
+        vals = jnp.take(one, src, axis=ax)
+        idx = (slice(None),) * ax + (dst,)
+        return full.at[idx].set(vals.astype(full.dtype))
+    return jax.tree_util.tree_map(put, cache, wave_cache, cache_spec)
+
+
+def copy_blocks(cache, src, dst, *, pool_leaves):
+    """Copy pool blocks ``src`` onto ``dst`` on every pool leaf — the
+    device half of copy-on-write (one batched dispatch per chunk)."""
+    new = dict(cache)
+    for name in pool_leaves:
+        new[name] = cache[name].at[:, dst].set(cache[name][:, src])
+    return new
+
+
+def _bind(fn, **kw):
+    """``functools.partial`` that keeps ``fn``'s name: a jitted function's
+    name is its program's (``jit_<name>``) in profiler traces."""
+    return functools.update_wrapper(functools.partial(fn, **kw), fn)
+
+
+def _rids(reqs) -> str:
+    """Request ids of a dispatch as one span stat (spaces: a comma would
+    split the profiler's stat encoding)."""
+    return " ".join(str(r.rid) for r in reqs)
 
 
 def _pow2_bucket(n: int, lo: int, hi: int) -> int:
@@ -510,7 +558,9 @@ class ServeEngine:
                 n_slots, self.num_blocks, kv_block_size, self.max_blocks)
             self._pool_leaves = [
                 k for k, ax in self.api.paged_cache_spec.items() if ax == 1]
-            self._copier = jax.jit(self._copy_blocks, donate_argnums=(0,))
+            self._copier = jax.jit(
+                _bind(copy_blocks, pool_leaves=tuple(self._pool_leaves)),
+                donate_argnums=(0,))
         else:
             self.pager = None
             self.cache = self.api.init_cache(n_slots, max_len)
@@ -570,8 +620,10 @@ class ServeEngine:
         self._chunk_fns = {}          # (n, greedy) -> jit scan-decode fn
         self._spec_fns = {}           # k -> (jit draft scan, jit verify scan)
         self._prefill_cache = {}      # (wave_bucket, padded_len) -> jit fn
-        self._writer = jax.jit(self._write_wave, donate_argnums=(0,))
-        self._sampler = jax.jit(_sample_tokens,
+        self._writer = jax.jit(
+            _bind(write_wave, cache_spec=self.api.cache_spec),
+            donate_argnums=(0,))
+        self._sampler = jax.jit(sample_tokens,
                                 static_argnames=("greedy", "vocab_size"))
 
     def _validate_cache_spec(self):
@@ -660,14 +712,6 @@ class ServeEngine:
                                 rules=self._rules)
         return jax.tree_util.tree_map(jax.lax.with_sharding_constraint,
                                       wave_cache, specs)
-
-    def _copy_blocks(self, cache, src, dst):
-        """Copy pool blocks ``src`` onto ``dst`` on every pool leaf — the
-        device half of copy-on-write (one batched dispatch per chunk)."""
-        new = dict(cache)
-        for name in self._pool_leaves:
-            new[name] = cache[name].at[:, dst].set(cache[name][:, src])
-        return new
 
     def _validate_adapters(self, reg: AdapterRegistry):
         """Adapter-aware deployment validation: the family must expose the
@@ -802,42 +846,44 @@ class ServeEngine:
         recomputes). The request re-enters the queue with its original
         rid, i.e. ahead of its priority class."""
         r = self.slots[i]
-        if self.paged and r.prefilling:
-            # a mid-prefill victim's consumed prefix is whole blocks (the
-            # cursor is block-aligned): publish them and drop the slot —
-            # no host tail to save, no SwapState. Re-admission goes back
-            # through the normal prefill path, where the radix match
-            # re-hits the published prefix, so the restore is
-            # token-identical without carrying any device state.
-            if r.adapter is None:
-                self.pager.insert(self._admission_seq(r)[:r.prefill_cursor],
-                                  self.pager.slot_blocks(i))
-            r.prefilling = False
-            r.prefill_cursor = 0
-            self.pager.release_slot(i)
-            self.stats.blocks_in_use = self.pager.blocks_in_use
-            self.stats.preempted_prefill += 1
-        elif self.paged:
-            seq = self._kv_seq(r)
-            bs = self.kv_block_size
-            full = len(seq) // bs
-            blocks = self.pager.slot_blocks(i)
-            if r.adapter is None and full:
-                self.pager.insert(seq, blocks[:full])
-            tail = None
-            if len(seq) % bs and full < len(blocks):
-                tb = blocks[full]
-                tail = {name: np.asarray(self.cache[name][:, tb])
-                        for name in self._pool_leaves}
-            r._swap = SwapState(seq_len=len(seq), full_blocks=full,
-                                tail=tail)
-            self.pager.release_slot(i)
-            self.stats.blocks_in_use = self.pager.blocks_in_use
-        self.slots[i] = None
-        self.adapter_slots[i] = -1
-        r.preemptions += 1
-        self.stats.preempted += 1
-        self.queue.push_front(r)
+        with TraceAnnotation("engine.preempt", rid=r.rid):
+            if self.paged and r.prefilling:
+                # a mid-prefill victim's consumed prefix is whole blocks (the
+                # cursor is block-aligned): publish them and drop the slot —
+                # no host tail to save, no SwapState. Re-admission goes back
+                # through the normal prefill path, where the radix match
+                # re-hits the published prefix, so the restore is
+                # token-identical without carrying any device state.
+                if r.adapter is None:
+                    done = self._admission_seq(r)[:r.prefill_cursor]
+                    self.pager.insert(done, self.pager.slot_blocks(i))
+                r.prefilling = False
+                r.prefill_cursor = 0
+                self.pager.release_slot(i)
+                self.stats.blocks_in_use = self.pager.blocks_in_use
+                self.stats.preempted_prefill += 1
+            elif self.paged:
+                seq = self._kv_seq(r)
+                bs = self.kv_block_size
+                full = len(seq) // bs
+                blocks = self.pager.slot_blocks(i)
+                if r.adapter is None and full:
+                    self.pager.insert(seq, blocks[:full])
+                tail = None
+                if len(seq) % bs and full < len(blocks):
+                    tb = blocks[full]
+                    with TraceAnnotation("engine.sync", what="swap"):
+                        tail = {name: np.asarray(self.cache[name][:, tb])
+                                for name in self._pool_leaves}
+                r._swap = SwapState(seq_len=len(seq), full_blocks=full,
+                                    tail=tail)
+                self.pager.release_slot(i)
+                self.stats.blocks_in_use = self.pager.blocks_in_use
+            self.slots[i] = None
+            self.adapter_slots[i] = -1
+            r.preemptions += 1
+            self.stats.preempted += 1
+            self.queue.push_front(r)
 
     def _try_fast_restore(self, r: Request, slot: int) -> bool:
         """Re-seat a swapped-out request without recompute: every full KV
@@ -1027,52 +1073,55 @@ class ServeEngine:
 
     # -- prefill waves ---------------------------------------------------------
     def _admit(self):
-        for r in self.queue.expire(self._now()):
-            self._finish(r, "expired")
-        self._expire_deadlines()
-        self._priority_preempt()
-        self._continue_prefill()
-        free = self._free_slots()
-        if not free or not self.queue:
-            return
-        take = self.queue.take(len(free))
-        pending = []
-        for r in take:
-            if self.paged and r._swap is not None and free \
-                    and self._try_fast_restore(r, free[0]):
-                free.pop(0)
-                continue
-            pending.append(r)
-        if not pending:
-            return
-        if self.api.ragged_prefill:
-            groups = [pending]
-        else:
-            by_len = {}
-            for r in pending:
-                by_len.setdefault(len(self._admission_seq(r)), []).append(r)
-            groups = list(by_len.values())
-        t0 = time.perf_counter()
-        gi = -1
-        try:
-            for gi, group in enumerate(groups):
-                if self.paged:
-                    self._prefill_group_paged(group, free)
-                else:
-                    self._prefill_group(group, free)
-            jax.block_until_ready(
-                self.cache["k"] if "k" in self.cache
-                else jax.tree_util.tree_leaves(self.cache)[0])
-        except Exception:
-            # the failing group requeued itself (its prefill handler owns
-            # rollback); untouched later groups must requeue here or
-            # they'd be lost with their adapter pins held forever
-            for group in groups[gi + 1:]:
-                for r in group:
-                    self.queue.push_front(r)
-            raise
-        finally:
-            self.stats.prefill_wall_s += time.perf_counter() - t0
+        with TraceAnnotation("engine.admit"):
+            for r in self.queue.expire(self._now()):
+                self._finish(r, "expired")
+            self._expire_deadlines()
+            self._priority_preempt()
+            self._continue_prefill()
+            free = self._free_slots()
+            if not free or not self.queue:
+                return
+            take = self.queue.take(len(free))
+            pending = []
+            for r in take:
+                if self.paged and r._swap is not None and free \
+                        and self._try_fast_restore(r, free[0]):
+                    free.pop(0)
+                    continue
+                pending.append(r)
+            if not pending:
+                return
+            if self.api.ragged_prefill:
+                groups = [pending]
+            else:
+                by_len = {}
+                for r in pending:
+                    n = len(self._admission_seq(r))
+                    by_len.setdefault(n, []).append(r)
+                groups = list(by_len.values())
+            t0 = time.perf_counter()
+            gi = -1
+            try:
+                for gi, group in enumerate(groups):
+                    if self.paged:
+                        self._prefill_group_paged(group, free)
+                    else:
+                        self._prefill_group(group, free)
+                with TraceAnnotation("engine.prefill_sync"):
+                    jax.block_until_ready(
+                        self.cache["k"] if "k" in self.cache
+                        else jax.tree_util.tree_leaves(self.cache)[0])
+            except Exception:
+                # the failing group requeued itself (its prefill handler
+                # owns rollback); untouched later groups must requeue here
+                # or they'd be lost with their adapter pins held forever
+                for group in groups[gi + 1:]:
+                    for r in group:
+                        self.queue.push_front(r)
+                raise
+            finally:
+                self.stats.prefill_wall_s += time.perf_counter() - t0
 
     def _get_prefill(self, wave_bucket: int, padded_len: int):
         """Jitted prefill for one (wave, padded_len) bucket. With an
@@ -1086,7 +1135,7 @@ class ServeEngine:
             scaling = self.registry.scaling if lora else None
             ragged = api.ragged_prefill
 
-            def fn(params, toks, lengths, stacked=None, aidx=None):
+            def prefill(params, toks, lengths, stacked=None, aidx=None):
                 cache = api.init_cache(toks.shape[0], max_len)
                 kw = {}
                 if ragged:
@@ -1099,11 +1148,12 @@ class ServeEngine:
                 return logits, self._constrain_wave(wave_cache,
                                                     toks.shape[0])
 
-            self._prefill_cache[key] = jax.jit(fn)
+            self._prefill_cache[key] = jax.jit(prefill)
             self.stats.prefill_compiles += 1
         return self._prefill_cache[key]
 
     def _prefill_group(self, group: List[Request], free: List[int]):
+        t_seat = self._now()
         w = len(group)
         wb = _pow2_bucket(w, 1, self.n_slots)
         seqs = [self._admission_seq(r) for r in group]
@@ -1122,16 +1172,18 @@ class ServeEngine:
                 aidx[i] = self.registry.index_of(r.adapter)
         fn = self._get_prefill(wb, pl)
         try:
-            if self.fault_hook is not None:
-                self.fault_hook("prefill")
-            if self.registry is not None:
-                logits, wave_cache = fn(self.params, jnp.asarray(toks),
-                                        jnp.asarray(lengths),
-                                        self.registry.stacked,
-                                        jnp.asarray(aidx))
-            else:
-                logits, wave_cache = fn(self.params, jnp.asarray(toks),
-                                        jnp.asarray(lengths))
+            with TraceAnnotation("engine.prefill", rows=w, tokens=sum(lens),
+                                 hit=0, rids=_rids(group), kind="first"):
+                if self.fault_hook is not None:
+                    self.fault_hook("prefill")
+                if self.registry is not None:
+                    logits, wave_cache = fn(self.params, jnp.asarray(toks),
+                                            jnp.asarray(lengths),
+                                            self.registry.stacked,
+                                            jnp.asarray(aidx))
+                else:
+                    logits, wave_cache = fn(self.params, jnp.asarray(toks),
+                                            jnp.asarray(lengths))
         except Exception:
             # nothing was mutated yet (no slot/cache writes): requeue the
             # whole group so no request — or its adapter pin — is lost
@@ -1141,61 +1193,49 @@ class ServeEngine:
         first = self._sample(logits)
         now = self._now()
         src, dst = [], []
-        for i, r in enumerate(group):
-            r.tokens.append(int(first[i]))
-            if not r._admitted:
-                r._admitted = True
-                self.stats.admitted += 1
-                if r.adapter is not None:
-                    self.stats.lora_requests += 1
-            else:
-                self.stats.restored += 1    # recompute restore
-            r.t_last = now
-            r._swap = None
-            self.stats.prefill_tokens += int(lengths[i])
-            want_cancel = self._emit(r, now)
-            reason = self._stop_reason(r)
-            if reason is None and want_cancel:
-                reason = "cancelled"
-            if reason is not None:
-                self._finish(r, reason)   # EOS/max_new on the first token
-                continue
-            slot = free.pop(0)
-            self.slots[slot] = r
-            self.adapter_slots[slot] = aidx[i]
-            src.append(i)
-            dst.append(slot)
+        with TraceAnnotation("engine.emit"):
+            for i, r in enumerate(group):
+                r.tokens.append(int(first[i]))
+                self._count_admission(r, t_seat)
+                r.t_last = now
+                r._swap = None
+                self.stats.prefill_tokens += int(lengths[i])
+                want_cancel = self._emit(r, now)
+                reason = self._stop_reason(r)
+                if reason is None and want_cancel:
+                    reason = "cancelled"
+                if reason is not None:
+                    self._finish(r, reason)   # EOS/max_new on first token
+                    continue
+                slot = free.pop(0)
+                self.slots[slot] = r
+                self.adapter_slots[slot] = aidx[i]
+                src.append(i)
+                dst.append(slot)
         if src:
-            self.cache = self._writer(self.cache, wave_cache,
-                                      jnp.asarray(src, jnp.int32),
-                                      jnp.asarray(dst, jnp.int32))
+            src = np.asarray(src, np.int32)
+            dst = np.asarray(dst, np.int32)
+            self.cache = self._writer(self.cache, wave_cache, src, dst)
             if self.speculate:
                 # the draft prefills the same wave (its logits are unused:
                 # the first token always comes from the target above), so
                 # seated slots start each spec round with draft KV covering
                 # exactly the target's positions. Same jitted fn — params
                 # are jit arguments, the draft's structure traces once.
-                if self.registry is not None:
-                    _, dwave = fn(self.draft_params, jnp.asarray(toks),
-                                  jnp.asarray(lengths),
-                                  self.registry.stacked, jnp.asarray(aidx))
-                else:
-                    _, dwave = fn(self.draft_params, jnp.asarray(toks),
-                                  jnp.asarray(lengths))
+                with TraceAnnotation("engine.prefill", rows=w,
+                                     tokens=sum(lens), hit=0,
+                                     rids=_rids(group), kind="draft"):
+                    if self.registry is not None:
+                        _, dwave = fn(self.draft_params, jnp.asarray(toks),
+                                      jnp.asarray(lengths),
+                                      self.registry.stacked,
+                                      jnp.asarray(aidx))
+                    else:
+                        _, dwave = fn(self.draft_params, jnp.asarray(toks),
+                                      jnp.asarray(lengths))
                 self.draft_cache = self._writer(self.draft_cache, dwave,
-                                                jnp.asarray(src, jnp.int32),
-                                                jnp.asarray(dst, jnp.int32))
+                                                src, dst)
         self.stats.prefill_waves += 1
-
-    def _write_wave(self, cache, wave_cache, src, dst):
-        """Copy wave rows `src` into engine slots `dst` on each leaf's
-        declared batch axis (api.cache_spec)."""
-        def put(full, one, ax):
-            vals = jnp.take(one, src, axis=ax)
-            idx = (slice(None),) * ax + (dst,)
-            return full.at[idx].set(vals.astype(full.dtype))
-        return jax.tree_util.tree_map(put, cache, wave_cache,
-                                      self.api.cache_spec)
 
     # -- paged prefill (block pool + prefix reuse) -----------------------------
     def _get_paged_prefill(self, wave_bucket: int, padded_len: int,
@@ -1215,8 +1255,8 @@ class ServeEngine:
             lora = self.registry is not None
             scaling = self.registry.scaling if lora else None
 
-            def fn(cache, params, toks, lengths, prefix_len, pbt, sbt,
-                   stacked=None, aidx=None):
+            def paged_prefill(cache, params, toks, lengths, prefix_len, pbt,
+                              sbt, stacked=None, aidx=None):
                 wave = api.init_cache(toks.shape[0], padded_len)
                 kw = {"lengths": lengths}
                 if n_prefix_blocks:
@@ -1245,7 +1285,8 @@ class ServeEngine:
                         w.astype(cache[name].dtype))
                 return logits, new_cache
 
-            self._prefill_cache[key] = jax.jit(fn, donate_argnums=(0,))
+            self._prefill_cache[key] = jax.jit(paged_prefill,
+                                               donate_argnums=(0,))
             self.stats.prefill_compiles += 1
         return self._prefill_cache[key]
 
@@ -1264,6 +1305,7 @@ class ServeEngine:
         thrash (the victim would immediately compete for the same
         blocks). An exception during the prefill dispatch rolls every
         admitted request's blocks back and requeues the wave."""
+        t_seat = self._now()
         pgr, bs = self.pager, self.kv_block_size
         budgeted = self.prefill_budget is not None
         admitted, slots_for = [], []    # slots are assigned up front: block
@@ -1330,15 +1372,12 @@ class ServeEngine:
             if r.adapter is not None:
                 aidx[i] = self.registry.index_of(r.adapter)
         fn = self._get_paged_prefill(wb, pl, npb)
-        args = [self.cache, self.params, jnp.asarray(toks),
-                jnp.asarray(lengths), jnp.asarray(prefix_len),
-                jnp.asarray(pbt), jnp.asarray(sbt)]
-        if self.registry is not None:
-            args += [self.registry.stacked, jnp.asarray(aidx)]
         try:
-            if self.fault_hook is not None:
-                self.fault_hook("prefill")
-            logits, self.cache = fn(*args)
+            with TraceAnnotation("engine.prefill", rows=w, tokens=sum(takes),
+                                 hit=sum(hit_toks), rids=_rids(admitted),
+                                 kind="first"):
+                logits, self.cache = self._dispatch_paged_prefill(
+                    fn, toks, lengths, prefix_len, pbt, sbt, aidx)
         except Exception:
             # roll the wave back: every admitted request's blocks return
             # to the pool and the requests (pins intact) requeue
@@ -1349,59 +1388,82 @@ class ServeEngine:
             raise
         first = self._sample(logits)
         now = self._now()
-        for i, (r, slot) in enumerate(zip(admitted, slots_for)):
-            if not r._admitted:
-                r._admitted = True
-                self.stats.admitted += 1
-                if r.adapter is not None:
-                    self.stats.lora_requests += 1
-            else:
-                self.stats.restored += 1    # recompute restore
-            r._swap = None
-            self.stats.prefill_tokens += int(lengths[i])
-            self.stats.prefix_hit_tokens += hit_toks[i]
-            if budgeted:
-                self.stats.prefill_chunks += 1
-                self._prefill_progress = True
-            if hit_toks[i] + takes[i] < len(seqs[i]):
-                # partial first chunk: the slot seats mid-prefill with a
-                # block-aligned cursor and NO token (the chunk's last-
-                # position logits are mid-prompt and discarded — greedy
-                # output stays bit-identical to an unbudgeted prefill).
-                # Publish the consumed whole blocks now so concurrent
-                # requests (and a preemption/restore) reuse them.
-                r.prefilling = True
-                r.prefill_cursor = hit_toks[i] + takes[i]
+        with TraceAnnotation("engine.emit"):
+            for i, (r, slot) in enumerate(zip(admitted, slots_for)):
+                self._count_admission(r, t_seat)
+                r._swap = None
+                self.stats.prefill_tokens += int(lengths[i])
+                self.stats.prefix_hit_tokens += hit_toks[i]
+                if budgeted:
+                    self.stats.prefill_chunks += 1
+                    self._prefill_progress = True
+                if hit_toks[i] + takes[i] < len(seqs[i]):
+                    # partial first chunk: the slot seats mid-prefill with a
+                    # block-aligned cursor and NO token (the chunk's last-
+                    # position logits are mid-prompt and discarded — greedy
+                    # output stays bit-identical to an unbudgeted prefill).
+                    # Publish the consumed whole blocks now so concurrent
+                    # requests (and a preemption/restore) reuse them.
+                    r.prefilling = True
+                    r.prefill_cursor = hit_toks[i] + takes[i]
+                    if r.adapter is None:
+                        pgr.insert(seqs[i][:r.prefill_cursor],
+                                   pgr.slot_blocks(slot))
+                    self.slots[slot] = r
+                    self.adapter_slots[slot] = aidx[i]
+                    continue
+                r.tokens.append(int(first[i]))
+                r.prefilling = False
+                r.prefill_cursor = len(seqs[i])
+                r.t_last = now
+                # publish the sequence's full blocks now: requests in later
+                # waves reuse this prefill while the slot is still decoding
+                # (base model only — LoRA KV is adapter-specific, see above)
                 if r.adapter is None:
-                    pgr.insert(seqs[i][:r.prefill_cursor],
-                               pgr.slot_blocks(slot))
+                    pgr.insert(seqs[i], pgr.slot_blocks(slot))
+                want_cancel = self._emit(r, now)
+                reason = self._stop_reason(r)
+                if reason is None and want_cancel:
+                    reason = "cancelled"
+                if reason is not None:
+                    pgr.release_slot(slot)
+                    self._finish(r, reason)   # EOS/max_new on the first token
+                    free.append(slot)         # reusable by the next group
+                    continue
                 self.slots[slot] = r
                 self.adapter_slots[slot] = aidx[i]
-                continue
-            r.tokens.append(int(first[i]))
-            r.prefilling = False
-            r.prefill_cursor = len(seqs[i])
-            r.t_last = now
-            # publish the sequence's full blocks now: requests in later
-            # waves reuse this prefill while the slot is still decoding
-            # (base model only — LoRA KV is adapter-specific, see above)
-            if r.adapter is None:
-                pgr.insert(seqs[i], pgr.slot_blocks(slot))
-            want_cancel = self._emit(r, now)
-            reason = self._stop_reason(r)
-            if reason is None and want_cancel:
-                reason = "cancelled"
-            if reason is not None:
-                pgr.release_slot(slot)
-                self._finish(r, reason)   # EOS/max_new on the first token
-                free.append(slot)         # reusable by the next group
-                continue
-            self.slots[slot] = r
-            self.adapter_slots[slot] = aidx[i]
         if self.speculate:
             self._draft_prefill_paged(admitted, slots_for, seqs)
         self.stats.prefill_waves += 1
         self.stats.blocks_in_use = pgr.blocks_in_use
+
+    def _dispatch_paged_prefill(self, fn, toks, lengths, prefix_len, pbt,
+                                sbt, aidx):
+        """Upload one paged wave's host arrays and dispatch its program
+        (pool donated); the fault hook fires first."""
+        args = [self.cache, self.params, jnp.asarray(toks),
+                jnp.asarray(lengths), jnp.asarray(prefix_len),
+                jnp.asarray(pbt), jnp.asarray(sbt)]
+        if self.registry is not None:
+            args += [self.registry.stacked, jnp.asarray(aidx)]
+        if self.fault_hook is not None:
+            self.fault_hook("prefill")
+        return fn(*args)
+
+    def _count_admission(self, r: Request, t_seat: float):
+        """Admission bookkeeping of a prefilled row: a request seated for
+        the first time is counted admitted and stamped ``t_admit`` (the
+        wave's start; its queue wait is summed); one seated before is a
+        recompute restore."""
+        if r._admitted:
+            self.stats.restored += 1
+            return
+        r._admitted = True
+        r.t_admit = t_seat
+        self.stats.admitted += 1
+        self.stats.queue_wait_s += t_seat - r.t_submit
+        if r.adapter is not None:
+            self.stats.lora_requests += 1
 
     def _continue_prefill(self):
         """Advance every mid-prefill slot by one budgeted chunk.
@@ -1486,15 +1548,13 @@ class ServeEngine:
             sbt[j, :nsb] = pgr.tables[slot, nh: nh + nsb]
             aidx[j] = self.adapter_slots[slot]
         fn = self._get_paged_prefill(wb, pl, npb)
-        args = [self.cache, self.params, jnp.asarray(toks),
-                jnp.asarray(lengths), jnp.asarray(prefix_len),
-                jnp.asarray(pbt), jnp.asarray(sbt)]
-        if self.registry is not None:
-            args += [self.registry.stacked, jnp.asarray(aidx)]
         try:
-            if self.fault_hook is not None:
-                self.fault_hook("prefill")
-            logits, self.cache = fn(*args)
+            with TraceAnnotation("engine.prefill", rows=len(ran),
+                                 tokens=sum(t for *_, t in ran), hit=0,
+                                 rids=_rids(r for _, r, _, _ in ran),
+                                 kind="continue"):
+                logits, self.cache = self._dispatch_paged_prefill(
+                    fn, toks, lengths, prefix_len, pbt, sbt, aidx)
         except Exception:
             # roll the extension back to the cursor: the slots stay
             # seated mid-prefill and a retried step re-plans the exact
@@ -1505,30 +1565,31 @@ class ServeEngine:
             raise
         first = self._sample(logits)
         now = self._now()
-        for j, (slot, r, seq, take) in enumerate(ran):
-            r.prefill_cursor += take
-            self.stats.prefill_tokens += take
-            self.stats.prefill_chunks += 1
-            self._prefill_progress = True
-            if r.adapter is None:
-                pgr.insert(seq[:r.prefill_cursor], pgr.slot_blocks(slot))
-            if r.prefill_cursor < len(seq):
-                continue                # still mid-prompt
-            # final chunk: the wave's last-position logits are the real
-            # end-of-prompt logits — sample the first token and hand the
-            # slot to decode
-            r.prefilling = False
-            r.tokens.append(int(first[j]))
-            r.t_last = now
-            want_cancel = self._emit(r, now)
-            reason = self._stop_reason(r)
-            if reason is None and want_cancel:
-                reason = "cancelled"
-            if reason is not None:
-                pgr.release_slot(slot)
-                self._finish(r, reason)
-                self.slots[slot] = None
-                self.adapter_slots[slot] = -1
+        with TraceAnnotation("engine.emit"):
+            for j, (slot, r, seq, take) in enumerate(ran):
+                r.prefill_cursor += take
+                self.stats.prefill_tokens += take
+                self.stats.prefill_chunks += 1
+                self._prefill_progress = True
+                if r.adapter is None:
+                    pgr.insert(seq[:r.prefill_cursor], pgr.slot_blocks(slot))
+                if r.prefill_cursor < len(seq):
+                    continue                # still mid-prompt
+                # final chunk: the wave's last-position logits are the real
+                # end-of-prompt logits — sample the first token and hand the
+                # slot to decode
+                r.prefilling = False
+                r.tokens.append(int(first[j]))
+                r.t_last = now
+                want_cancel = self._emit(r, now)
+                reason = self._stop_reason(r)
+                if reason is None and want_cancel:
+                    reason = "cancelled"
+                if reason is not None:
+                    pgr.release_slot(slot)
+                    self._finish(r, reason)
+                    self.slots[slot] = None
+                    self.adapter_slots[slot] = -1
         self.stats.prefill_waves += 1
         self.stats.blocks_in_use = pgr.blocks_in_use
 
@@ -1556,15 +1617,19 @@ class ServeEngine:
             lengths[j] = len(seqs[i])
             aidx[j] = self.adapter_slots[slot]
         fn = self._get_prefill(wb, pl)
-        if self.registry is not None:
-            _, dwave = fn(self.draft_params, jnp.asarray(toks),
-                          jnp.asarray(lengths), self.registry.stacked,
-                          jnp.asarray(aidx))
-        else:
-            _, dwave = fn(self.draft_params, jnp.asarray(toks),
-                          jnp.asarray(lengths))
-        src = jnp.asarray(list(range(len(keep))), jnp.int32)
-        dst = jnp.asarray([slot for _, slot in keep], jnp.int32)
+        with TraceAnnotation("engine.prefill", rows=len(keep),
+                             tokens=int(lengths[:len(keep)].sum()), hit=0,
+                             rids=_rids(admitted[i] for i, _ in keep),
+                             kind="draft"):
+            if self.registry is not None:
+                _, dwave = fn(self.draft_params, jnp.asarray(toks),
+                              jnp.asarray(lengths), self.registry.stacked,
+                              jnp.asarray(aidx))
+            else:
+                _, dwave = fn(self.draft_params, jnp.asarray(toks),
+                              jnp.asarray(lengths))
+        src = np.arange(len(keep), dtype=np.int32)
+        dst = np.asarray([slot for _, slot in keep], np.int32)
         self.draft_cache = self._writer(self.draft_cache, dwave, src, dst)
 
     # -- sampling --------------------------------------------------------------
@@ -1572,7 +1637,8 @@ class ServeEngine:
         toks, self.rng = self._sampler(jnp.asarray(logits), self.rng,
                                        greedy=self.greedy,
                                        vocab_size=self.cfg.vocab_size)
-        return np.asarray(toks)
+        with TraceAnnotation("engine.sync", what="prefill"):
+            return np.asarray(toks)
 
     # -- stop conditions -------------------------------------------------------
     def _stop_reason(self, r: Request) -> Optional[str]:
@@ -1626,18 +1692,20 @@ class ServeEngine:
             api, cfg = self.api, self.cfg
             eos_id, max_len, greedy = self.eos_id, self.max_len, self.greedy
             if self.registry is None:
-                def fn(params, last, cache, rng, stop, gen, max_new):
+                def decode_chunk(params, last, cache, rng, stop, gen,
+                                 max_new):
                     return decode_steps(
                         api.decode, params, last, cache, rng, stop, gen,
                         max_new, n=n, vocab_size=cfg.vocab_size,
                         max_len=max_len, eos_id=eos_id, greedy=greedy)
 
-                self._chunk_fns[key] = jax.jit(fn, donate_argnums=(2,))
+                self._chunk_fns[key] = jax.jit(decode_chunk,
+                                               donate_argnums=(2,))
             else:
                 scaling = self.registry.scaling
 
-                def fn(params, stacked, aidx, last, cache, rng, stop, gen,
-                       max_new):
+                def decode_chunk(params, stacked, aidx, last, cache, rng,
+                                 stop, gen, max_new):
                     def dec(p, t, c):
                         return api.decode(p, t, c, adapters=stacked,
                                           adapter_idx=aidx,
@@ -1647,7 +1715,8 @@ class ServeEngine:
                         max_new, n=n, vocab_size=cfg.vocab_size,
                         max_len=max_len, eos_id=eos_id, greedy=greedy)
 
-                self._chunk_fns[key] = jax.jit(fn, donate_argnums=(4,))
+                self._chunk_fns[key] = jax.jit(decode_chunk,
+                                               donate_argnums=(4,))
         return self._chunk_fns[key]
 
     # -- speculative decode ----------------------------------------------------
@@ -1670,7 +1739,7 @@ class ServeEngine:
             vs = cfg.vocab_size
             no_stop_len = self.max_len + 2    # pos bound can never fire
             if self.registry is None:
-                def draft_fn(dparams, last, dcache, rng, stop):
+                def spec_draft(dparams, last, dcache, rng, stop):
                     b = last.shape[0]
                     return decode_steps(
                         api.decode, dparams, last, dcache, rng, stop,
@@ -1679,18 +1748,18 @@ class ServeEngine:
                         n=k + 1, vocab_size=vs, max_len=no_stop_len,
                         eos_id=None, greedy=True)
 
-                def verify_fn(params, last, drafts, cache):
+                def spec_verify(params, last, drafts, cache):
                     return verify_steps(api.decode, params, last, drafts,
                                         cache, vocab_size=vs)
 
                 self._spec_fns[k] = (
-                    jax.jit(draft_fn, donate_argnums=(2,)),
-                    jax.jit(verify_fn, donate_argnums=(3,)))
+                    jax.jit(spec_draft, donate_argnums=(2,)),
+                    jax.jit(spec_verify, donate_argnums=(3,)))
             else:
                 scaling = self.registry.scaling
 
-                def draft_fn(dparams, stacked, aidx, last, dcache, rng,
-                             stop):
+                def spec_draft(dparams, stacked, aidx, last, dcache, rng,
+                               stop):
                     def dec(p, t, c):
                         return api.decode(p, t, c, adapters=stacked,
                                           adapter_idx=aidx,
@@ -1703,7 +1772,7 @@ class ServeEngine:
                         n=k + 1, vocab_size=vs, max_len=no_stop_len,
                         eos_id=None, greedy=True)
 
-                def verify_fn(params, stacked, aidx, last, drafts, cache):
+                def spec_verify(params, stacked, aidx, last, drafts, cache):
                     def dec(p, t, c):
                         return api.decode(p, t, c, adapters=stacked,
                                           adapter_idx=aidx,
@@ -1712,8 +1781,8 @@ class ServeEngine:
                                         vocab_size=vs)
 
                 self._spec_fns[k] = (
-                    jax.jit(draft_fn, donate_argnums=(4,)),
-                    jax.jit(verify_fn, donate_argnums=(5,)))
+                    jax.jit(spec_draft, donate_argnums=(4,)),
+                    jax.jit(spec_verify, donate_argnums=(5,)))
         return self._spec_fns[k]
 
     def _spec_step(self, active, max_n: Optional[int]) -> bool:
@@ -1744,124 +1813,132 @@ class ServeEngine:
                 max_n=max_n)
 
         k = pick_k()
-        if self.paged:
-            # plan -> commit for the whole k+1 verify window, preempting
-            # while it cannot fit (mirrors `_step`; a single slot always
-            # fits because k is clamped to the slot's own remaining room)
-            while len(active) > 1:
-                need = 0
+        cow = []
+        with TraceAnnotation("engine.plan") as plan:
+            if self.paged:
+                # plan -> commit for the whole k+1 verify window, preempting
+                # while it cannot fit (mirrors `_step`; a single slot always
+                # fits because k is clamped to the slot's own remaining room)
+                while len(active) > 1:
+                    need = 0
+                    for i in active:
+                        a, c = self.pager.plan_decode(i, positions[i], k + 1)
+                        need += a + c
+                    if self.pager.can_allocate(need):
+                        break
+                    self._preempt_slot(pick_victim(self.slots))
+                    active = [i for i, s in enumerate(self.slots)
+                              if s is not None]
+                    k = pick_k()
+                pos_host = np.zeros((self.n_slots,), np.int32)
                 for i in active:
-                    a, c = self.pager.plan_decode(i, positions[i], k + 1)
-                    need += a + c
-                if self.pager.can_allocate(need):
-                    break
-                self._preempt_slot(pick_victim(self.slots))
-                active = [i for i, s in enumerate(self.slots)
-                          if s is not None]
-                k = pick_k()
-            cow = []
-            pos_host = np.zeros((self.n_slots,), np.int32)
-            for i in active:
-                pos_host[i] = positions[i]
-                cow += self.pager.prepare_decode(i, positions[i], k + 1)
-            if cow:
-                pad = _pow2_bucket(len(cow), 1, 1 << 30) - len(cow)
-                pairs = cow + [(0, 0)] * pad
-                self.cache = self._copier(
-                    self.cache,
-                    jnp.asarray([c[0] for c in pairs], jnp.int32),
-                    jnp.asarray([c[1] for c in pairs], jnp.int32))
-                self.stats.cow_copies += len(cow)
-            self.cache["pos"] = jnp.asarray(pos_host)
-            self.cache["block_tables"] = jnp.asarray(self.pager.tables)
-            self.stats.blocks_in_use = self.pager.blocks_in_use
-        else:
-            # dense rollback is this line: the verify scan advanced the
-            # device cursor to pos+k+1 last round, resetting it to the
-            # accepted length makes the stale tail dead weight the next
-            # window overwrites
-            pos_host = np.zeros((self.n_slots,), np.int32)
-            for i in active:
-                pos_host[i] = positions[i]
-            self.cache["pos"] = jnp.asarray(pos_host)
-        dpos = np.zeros((self.n_slots,), np.int32)
-        for i in active:
-            dpos[i] = positions[i]
-        self.draft_cache["pos"] = jnp.asarray(dpos)
-        last = np.zeros((self.n_slots,), np.int32)
-        stop = np.ones((self.n_slots,), bool)
-        for i in active:
-            last[i] = self.slots[i].tokens[-1]
-            stop[i] = False
-        draft_fn, verify_fn = self._get_spec_fns(k)
-        if k:
-            if self.fault_hook is not None:
-                self.fault_hook("draft")
-            if self.registry is not None:
-                dout = draft_fn(self.draft_params, self.registry.stacked,
-                                jnp.asarray(self.adapter_slots),
-                                jnp.asarray(last), self.draft_cache,
-                                self.rng, jnp.asarray(stop))
+                    pos_host[i] = positions[i]
+                    cow += self.pager.prepare_decode(i, positions[i], k + 1)
+                if cow:
+                    pad = _pow2_bucket(len(cow), 1, 1 << 30) - len(cow)
+                    pairs = cow + [(0, 0)] * pad
+                    src = np.asarray([c[0] for c in pairs], np.int32)
+                    dst = np.asarray([c[1] for c in pairs], np.int32)
+                    self.cache = self._copier(self.cache, src, dst)
+                    self.stats.cow_copies += len(cow)
+                self.cache["pos"] = jnp.asarray(pos_host)
+                self.cache["block_tables"] = jnp.asarray(self.pager.tables)
+                self.stats.blocks_in_use = self.pager.blocks_in_use
             else:
-                dout = draft_fn(self.draft_params, jnp.asarray(last),
-                                self.draft_cache, self.rng,
-                                jnp.asarray(stop))
+                # dense rollback is this line: the verify scan advanced the
+                # device cursor to pos+k+1 last round, resetting it to the
+                # accepted length makes the stale tail dead weight the next
+                # window overwrites
+                pos_host = np.zeros((self.n_slots,), np.int32)
+                for i in active:
+                    pos_host[i] = positions[i]
+                self.cache["pos"] = jnp.asarray(pos_host)
+            dpos = np.zeros((self.n_slots,), np.int32)
+            for i in active:
+                dpos[i] = positions[i]
+            self.draft_cache["pos"] = jnp.asarray(dpos)
+            last = np.zeros((self.n_slots,), np.int32)
+            stop = np.ones((self.n_slots,), bool)
+            for i in active:
+                last[i] = self.slots[i].tokens[-1]
+                stop[i] = False
+            plan.set_metadata(cow=len(cow))
+        draft_fn, verify_fn = self._get_spec_fns(k)
+        rids = _rids(self.slots[i] for i in active)
+        if k:
+            with TraceAnnotation("engine.decode", phase="draft", steps=k + 1,
+                                 rows=len(active), rids=rids):
+                if self.fault_hook is not None:
+                    self.fault_hook("draft")
+                if self.registry is not None:
+                    dout = draft_fn(self.draft_params, self.registry.stacked,
+                                    jnp.asarray(self.adapter_slots),
+                                    jnp.asarray(last), self.draft_cache,
+                                    self.rng, jnp.asarray(stop))
+                else:
+                    dout = draft_fn(self.draft_params, jnp.asarray(last),
+                                    self.draft_cache, self.rng,
+                                    jnp.asarray(stop))
             self.draft_cache = dout.cache
-            drafts_dev = dout.tokens[:k]
-            drafts = np.asarray(drafts_dev)
+            with TraceAnnotation("engine.sync", what="draft"):
+                drafts = np.asarray(dout.tokens)[:k]
         else:
-            drafts_dev = jnp.zeros((0, self.n_slots), jnp.int32)
             drafts = np.zeros((0, self.n_slots), np.int32)
-        if self.fault_hook is not None:
-            self.fault_hook("verify")
-        if self.registry is not None:
-            targets_dev, self.cache = verify_fn(
-                self.params, self.registry.stacked,
-                jnp.asarray(self.adapter_slots), jnp.asarray(last),
-                drafts_dev, self.cache)
-        else:
-            targets_dev, self.cache = verify_fn(
-                self.params, jnp.asarray(last), drafts_dev, self.cache)
-        targets = np.asarray(targets_dev)          # [k+1, B]
+        with TraceAnnotation("engine.decode", phase="verify", steps=k + 1,
+                             rows=len(active), rids=rids):
+            if self.fault_hook is not None:
+                self.fault_hook("verify")
+            if self.registry is not None:
+                targets_dev, self.cache = verify_fn(
+                    self.params, self.registry.stacked,
+                    jnp.asarray(self.adapter_slots), jnp.asarray(last),
+                    jnp.asarray(drafts), self.cache)
+            else:
+                targets_dev, self.cache = verify_fn(
+                    self.params, jnp.asarray(last), jnp.asarray(drafts),
+                    self.cache)
+        with TraceAnnotation("engine.sync", what="verify"):
+            targets = np.asarray(targets_dev)          # [k+1, B]
         now = self._now()
         emitted = 0
-        for i in active:
-            r = self.slots[i]
-            m = accept_length(drafts[:, i], targets[:, i])
-            got = 0
-            reason = None
-            for t in range(m + 1):
-                # stops are re-derived per appended token: an EOS / budget
-                # / cache-full landing mid-acceptance discards the rest
-                r.tokens.append(int(targets[t, i]))
-                got += 1
-                reason = self._stop_reason(r)
-                if reason is not None:
-                    break
-            r.t_last = now
-            emitted += got
-            self.stats.spec_slot_rounds += 1
-            self.stats.drafted_tokens += k
-            # of the kept tokens, all but a final correction/bonus token
-            # were draft proposals
-            self.stats.accepted_draft_tokens += min(got, m)
-            if self.paged:
-                # rollback: keep exactly the KV the kept tokens stand on
-                # (prompt ++ tokens[:-1]); whole tail blocks written for
-                # rejected positions return to the pool
-                self.pager.truncate(i, positions[i] + got)
-            want_cancel = self._emit(r, now)
-            if reason is None and want_cancel:
-                reason = "cancelled"
-            if reason is not None:
+        with TraceAnnotation("engine.emit"):
+            for i in active:
+                r = self.slots[i]
+                m = accept_length(drafts[:, i], targets[:, i])
+                got = 0
+                reason = None
+                for t in range(m + 1):
+                    # stops are re-derived per appended token: an EOS / budget
+                    # / cache-full landing mid-acceptance discards the rest
+                    r.tokens.append(int(targets[t, i]))
+                    got += 1
+                    reason = self._stop_reason(r)
+                    if reason is not None:
+                        break
+                r.t_last = now
+                emitted += got
+                self.stats.spec_slot_rounds += 1
+                self.stats.drafted_tokens += k
+                # of the kept tokens, all but a final correction/bonus token
+                # were draft proposals
+                self.stats.accepted_draft_tokens += min(got, m)
                 if self.paged:
-                    if r.adapter is None:
-                        self.pager.insert(self._kv_seq(r),
-                                          self.pager.slot_blocks(i))
-                    self.pager.release_slot(i)
-                self._finish(r, reason)
-                self.slots[i] = None
-                self.adapter_slots[i] = -1
+                    # rollback: keep exactly the KV the kept tokens stand on
+                    # (prompt ++ tokens[:-1]); whole tail blocks written for
+                    # rejected positions return to the pool
+                    self.pager.truncate(i, positions[i] + got)
+                want_cancel = self._emit(r, now)
+                if reason is None and want_cancel:
+                    reason = "cancelled"
+                if reason is not None:
+                    if self.paged:
+                        if r.adapter is None:
+                            self.pager.insert(self._kv_seq(r),
+                                              self.pager.slot_blocks(i))
+                        self.pager.release_slot(i)
+                    self._finish(r, reason)
+                    self.slots[i] = None
+                    self.adapter_slots[i] = -1
         self.stats.spec_rounds += 1
         self.stats.spec_emitted_tokens += emitted
         self.stats.decode_tokens += emitted
@@ -1878,7 +1955,9 @@ class ServeEngine:
         on-device steps. With an adapter registry the per-slot [n_slots]
         adapter-index row rides along so mixed base/LoRA slots decode in
         the same scan. Returns False when no work is left."""
-        with self._mesh_ctx():
+        with TraceAnnotation("engine.step",
+                             active=sum(s is not None for s in self.slots)), \
+                self._mesh_ctx():
             return self._step(max_n)
 
     def _chunk_len(self, active, max_n: Optional[int]) -> int:
@@ -1943,133 +2022,140 @@ class ServeEngine:
         if self.speculate:
             return self._spec_step(decode_active, max_n)
         n = self._chunk_len(decode_active, max_n)
-        if self.paged:
-            # plan -> commit: reserve the whole write window's block
-            # budget before touching the pool, preempting the lowest-
-            # priority slot while the window cannot fit. A single slot
-            # always fits (pool >= per-slot max + trash), so this
-            # terminates with at least one runner.
-            while True:
-                need = 0
+        cow = []
+        with TraceAnnotation("engine.plan") as plan:
+            if self.paged:
+                # plan -> commit: reserve the whole write window's block
+                # budget before touching the pool, preempting the lowest-
+                # priority slot while the window cannot fit. A single slot
+                # always fits (pool >= per-slot max + trash), so this
+                # terminates with at least one runner.
+                while True:
+                    need = 0
+                    for i in decode_active:
+                        r = self.slots[i]
+                        pos0 = len(r.prompt) + len(r.tokens) - 1
+                        rem = min(r.max_new - len(r.tokens),
+                                  self.max_len - pos0)
+                        a, c = self.pager.plan_decode(i, pos0,
+                                                      max(1, min(n, rem)))
+                        need += a + c
+                    if self.pager.can_allocate(need):
+                        break
+                    if sum(s is not None for s in self.slots) <= 1:
+                        break
+                    self._preempt_slot(pick_victim(self.slots))
+                    decode_active = [i for i, s in enumerate(self.slots)
+                                     if s is not None and not s.prefilling]
+                    if not decode_active:
+                        # the last decoder was the victim; the preemption
+                        # itself is this step's progress
+                        return True
+                    n = self._chunk_len(decode_active, max_n)
+            last = np.zeros((self.n_slots,), np.int32)
+            gen = np.zeros((self.n_slots,), np.int32)
+            budget = np.zeros((self.n_slots,), np.int32)
+            stop = np.ones((self.n_slots,), bool)
+            for i in decode_active:
+                r = self.slots[i]
+                last[i] = r.tokens[-1]
+                gen[i] = len(r.tokens)
+                budget[i] = r.max_new
+                stop[i] = False
+            if self.paged:
+                # make every active slot's write window [pos, pos+n) backed by
+                # uniquely owned blocks: append fresh blocks past the table end
+                # and copy-on-write any shared block, in ONE batched device
+                # copy. Planned above, so allocation cannot fail halfway; a
+                # re-run after a decode-phase fault is a no-op (idempotent).
+                pos_host = np.zeros((self.n_slots,), np.int32)
                 for i in decode_active:
                     r = self.slots[i]
                     pos0 = len(r.prompt) + len(r.tokens) - 1
-                    rem = min(r.max_new - len(r.tokens),
-                              self.max_len - pos0)
-                    a, c = self.pager.plan_decode(i, pos0,
-                                                  max(1, min(n, rem)))
-                    need += a + c
-                if self.pager.can_allocate(need):
-                    break
-                if sum(s is not None for s in self.slots) <= 1:
-                    break
-                self._preempt_slot(pick_victim(self.slots))
-                decode_active = [i for i, s in enumerate(self.slots)
-                                 if s is not None and not s.prefilling]
-                if not decode_active:
-                    # the last decoder was the victim; the preemption
-                    # itself is this step's progress
-                    return True
-                n = self._chunk_len(decode_active, max_n)
-        last = np.zeros((self.n_slots,), np.int32)
-        gen = np.zeros((self.n_slots,), np.int32)
-        budget = np.zeros((self.n_slots,), np.int32)
-        stop = np.ones((self.n_slots,), bool)
-        for i in decode_active:
-            r = self.slots[i]
-            last[i] = r.tokens[-1]
-            gen[i] = len(r.tokens)
-            budget[i] = r.max_new
-            stop[i] = False
-        if self.paged:
-            # make every active slot's write window [pos, pos+n) backed by
-            # uniquely owned blocks: append fresh blocks past the table end
-            # and copy-on-write any shared block, in ONE batched device
-            # copy. Planned above, so allocation cannot fail halfway; a
-            # re-run after a decode-phase fault is a no-op (idempotent).
-            cow = []
-            pos_host = np.zeros((self.n_slots,), np.int32)
-            for i in decode_active:
-                r = self.slots[i]
-                pos0 = len(r.prompt) + len(r.tokens) - 1
-                pos_host[i] = pos0
-                rem = min(r.max_new - len(r.tokens), self.max_len - pos0)
-                cow += self.pager.prepare_decode(i, pos0,
-                                                 max(1, min(n, rem)))
-            if cow:
-                # pad to a power-of-two count (trash onto trash) so the
-                # jitted copier compiles once per bucket, not per count
-                pad = _pow2_bucket(len(cow), 1, 1 << 30) - len(cow)
-                pairs = cow + [(0, 0)] * pad
-                src = jnp.asarray([c[0] for c in pairs], jnp.int32)
-                dst = jnp.asarray([c[1] for c in pairs], jnp.int32)
-                self.cache = self._copier(self.cache, src, dst)
-                self.stats.cow_copies += len(cow)
-            self.cache["pos"] = jnp.asarray(pos_host)
-            # the decode scan writes KV for EVERY row, every scan step
-            # (stopped rows freeze their token but not the cache write at
-            # pos). Free slots' table rows are already all-trash; a mid-
-            # prefill slot's row holds REAL blocks at index 0, which a
-            # write at pos=0 would corrupt — mask those rows to trash in
-            # the dispatched copy (the pager's own tables are untouched)
-            tables = self.pager.tables
-            if any(s is not None and s.prefilling for s in self.slots):
-                tables = tables.copy()
-                for i, s in enumerate(self.slots):
-                    if s is not None and s.prefilling:
-                        tables[i, :] = TRASH_BLOCK
-            self.cache["block_tables"] = jnp.asarray(tables)
-            self.stats.blocks_in_use = self.pager.blocks_in_use
+                    pos_host[i] = pos0
+                    rem = min(r.max_new - len(r.tokens), self.max_len - pos0)
+                    cow += self.pager.prepare_decode(i, pos0,
+                                                     max(1, min(n, rem)))
+                if cow:
+                    # pad to a power-of-two count (trash onto trash) so the
+                    # jitted copier compiles once per bucket, not per count
+                    pad = _pow2_bucket(len(cow), 1, 1 << 30) - len(cow)
+                    pairs = cow + [(0, 0)] * pad
+                    src = np.asarray([c[0] for c in pairs], np.int32)
+                    dst = np.asarray([c[1] for c in pairs], np.int32)
+                    self.cache = self._copier(self.cache, src, dst)
+                    self.stats.cow_copies += len(cow)
+                self.cache["pos"] = jnp.asarray(pos_host)
+                # the decode scan writes KV for EVERY row, every scan step
+                # (stopped rows freeze their token but not the cache write at
+                # pos). Free slots' table rows are already all-trash; a mid-
+                # prefill slot's row holds REAL blocks at index 0, which a
+                # write at pos=0 would corrupt — mask those rows to trash in
+                # the dispatched copy (the pager's own tables are untouched)
+                tables = self.pager.tables
+                if any(s is not None and s.prefilling for s in self.slots):
+                    tables = tables.copy()
+                    for i, s in enumerate(self.slots):
+                        if s is not None and s.prefilling:
+                            tables[i, :] = TRASH_BLOCK
+                self.cache["block_tables"] = jnp.asarray(tables)
+                self.stats.blocks_in_use = self.pager.blocks_in_use
+            plan.set_metadata(cow=len(cow))
         fn = self._get_chunk_fn(n)
-        if self.fault_hook is not None:
-            # after the (idempotent) pager commit, before the dispatch:
-            # a fault here leaves the step cleanly retryable
-            self.fault_hook("decode")
-        if self.registry is not None:
-            out = fn(self.params, self.registry.stacked,
-                     jnp.asarray(self.adapter_slots), jnp.asarray(last),
-                     self.cache, self.rng, jnp.asarray(stop),
-                     jnp.asarray(gen), jnp.asarray(budget))
-        else:
-            out = fn(self.params, jnp.asarray(last), self.cache, self.rng,
-                     jnp.asarray(stop), jnp.asarray(gen),
-                     jnp.asarray(budget))
+        with TraceAnnotation("engine.decode", steps=n,
+                             rows=len(decode_active),
+                             rids=_rids(self.slots[i] for i in decode_active)):
+            if self.fault_hook is not None:
+                # after the (idempotent) pager commit, before the dispatch:
+                # a fault here leaves the step cleanly retryable
+                self.fault_hook("decode")
+            if self.registry is not None:
+                out = fn(self.params, self.registry.stacked,
+                         jnp.asarray(self.adapter_slots), jnp.asarray(last),
+                         self.cache, self.rng, jnp.asarray(stop),
+                         jnp.asarray(gen), jnp.asarray(budget))
+            else:
+                out = fn(self.params, jnp.asarray(last), self.cache,
+                         self.rng, jnp.asarray(stop), jnp.asarray(gen),
+                         jnp.asarray(budget))
         self.cache, self.rng = out.cache, out.rng
-        toks = np.asarray(out.tokens)
-        valid = np.asarray(out.valid)
+        with TraceAnnotation("engine.sync", what="decode"):
+            toks = np.asarray(out.tokens)
+            valid = np.asarray(out.valid)
         self.stats.steps += n
         self.stats.decode_chunks += 1
         self.stats.decode_tokens += int(valid.sum())
         self.stats.occupancy_sum += float(valid.sum()) / self.n_slots
         now = self._now()
-        for i in decode_active:
-            r = self.slots[i]
-            got = 0
-            for t in range(n):
-                if not valid[t, i]:
-                    break
-                r.tokens.append(int(toks[t, i]))
-                got += 1
-            if got:
-                r.t_last = now
-            want_cancel = self._emit(r, now)
-            reason = self._stop_reason(r)
-            if reason is None and want_cancel:
-                reason = "cancelled"
-            if reason is not None:
-                if self.paged:
-                    # publish the generated tokens' full blocks too (KV at
-                    # position p is keyed by prompt ++ tokens[:-1], the
-                    # sequence actually fed), then drop the slot's refs —
-                    # indexed blocks stay cached for future requests.
-                    # LoRA rows stay unindexed (adapter-specific KV).
-                    if r.adapter is None:
-                        self.pager.insert(self._kv_seq(r),
-                                          self.pager.slot_blocks(i))
-                    self.pager.release_slot(i)
-                self._finish(r, reason)
-                self.slots[i] = None
-                self.adapter_slots[i] = -1
+        with TraceAnnotation("engine.emit"):
+            for i in decode_active:
+                r = self.slots[i]
+                got = 0
+                for t in range(n):
+                    if not valid[t, i]:
+                        break
+                    r.tokens.append(int(toks[t, i]))
+                    got += 1
+                if got:
+                    r.t_last = now
+                want_cancel = self._emit(r, now)
+                reason = self._stop_reason(r)
+                if reason is None and want_cancel:
+                    reason = "cancelled"
+                if reason is not None:
+                    if self.paged:
+                        # publish the generated tokens' full blocks too (KV at
+                        # position p is keyed by prompt ++ tokens[:-1], the
+                        # sequence actually fed), then drop the slot's refs —
+                        # indexed blocks stay cached for future requests.
+                        # LoRA rows stay unindexed (adapter-specific KV).
+                        if r.adapter is None:
+                            self.pager.insert(self._kv_seq(r),
+                                              self.pager.slot_blocks(i))
+                        self.pager.release_slot(i)
+                    self._finish(r, reason)
+                    self.slots[i] = None
+                    self.adapter_slots[i] = -1
         if self.paged:
             self.stats.blocks_in_use = self.pager.blocks_in_use
         return True
