@@ -87,6 +87,11 @@ class QTensor:
                 per_channel -> [*, 1, out]
                 per_group   -> [*, in//g, 1, out]   (dequant reshapes)
     codebook: f32 [2**bits] normalized levels in [-1, 1], or None for affine.
+    layer:    None, or an int32 index into the leading (stacked-layers) axis
+              of ``codes``: the tensor then stands for that one layer while
+              its codes stay whole, so a kernel can read the layer's tiles in
+              place (``scale`` is already that layer's). Consumers that need
+              a per-layer operand take :func:`layer_slice` first.
     """
 
     codes: Array
@@ -98,20 +103,21 @@ class QTensor:
     group_size: int
     packed: bool
     shape: tuple  # logical (unpacked) shape
+    layer: Optional[Array] = None
 
     # -- pytree protocol ----------------------------------------------------
     def tree_flatten(self):
-        children = (self.codes, self.scale, self.codebook)
+        children = (self.codes, self.scale, self.codebook, self.layer)
         aux = (self.bits, self.mode, self.granularity, self.group_size,
                self.packed, self.shape)
         return children, aux
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        codes, scale, codebook = children
+        codes, scale, codebook, layer = children
         bits, mode, granularity, group_size, packed, shape = aux
         return cls(codes, scale, codebook, bits, mode, granularity,
-                   group_size, packed, shape)
+                   group_size, packed, shape, layer)
 
     # -- convenience ---------------------------------------------------------
     @property
@@ -267,6 +273,15 @@ def quantize(w: Array, cfg: QuantConfig) -> QTensor:
                    group_size=cfg.group_size, packed=packed, shape=w.shape)
 
 
+def layer_slice(qt: QTensor) -> QTensor:
+    """The QTensor with ``codes[layer]`` materialized when ``layer`` is set
+    (what scanning over the stacked codes would have handed out); the
+    tensor itself otherwise."""
+    if qt.layer is None:
+        return qt
+    return dataclasses.replace(qt, codes=qt.codes[qt.layer], layer=None)
+
+
 def decode_codes(qt: QTensor) -> Array:
     """Return unpacked signed integer codes with qt.shape."""
     if qt.packed:
@@ -289,6 +304,7 @@ def lookup(qt: QTensor, codes: Array) -> Array:
 
 
 def dequantize(qt: QTensor, dtype=jnp.float32) -> Array:
+    qt = layer_slice(qt)
     codes = decode_codes(qt)
     normed = lookup(qt, codes)
     if qt.granularity == "per_group":

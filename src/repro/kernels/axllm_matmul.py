@@ -11,10 +11,16 @@ The HBM traffic is `bytes(int8 codes) = N·M` instead of `2·N·M` (bf16) or
 
 Layout & tiling
   x     [M, K]   activations (bf16/f32), blocked (bm, bk)
-  codes [K, N]   int8 (or uint8-packed int4), blocked (bk, bn)
+  codes [L, K, N] int8 (or uint8-packed int4), blocked (None, bk, bn) at
+                 the prefetched ``layer``; a [K, N] weight is L = 1
   scale per-channel [1, N] f32, blocked (1, bn)       (affine / codebook)
         per-group  [K/g, N] f32, blocked (bk/g, bn)   (per_group affine)
   out   [M, N]   f32 accumulation across the K grid dimension.
+
+``codes`` may be the whole stack of a scanned model's layers, [L, K, N] (or
+[L, K, N/2] packed), with the layer index prefetched into SMEM: the codes
+index map reads layer ``l``'s tiles straight out of the stack, so no
+per-layer [K, N] copy is made before the call.
 
 Grid = (M/bm, N/bn, K/bk), K innermost ("arbitrary" semantics) so the f32
 accumulator tile persists in VMEM scratch across K steps. MXU-aligned block
@@ -98,9 +104,10 @@ def _dot(x, w):
                        preferred_element_type=jnp.float32)
 
 
-def _axllm_kernel(x_ref, codes_ref, scale_ref, *rest, bits: int,
+def _axllm_kernel(layer_ref, x_ref, codes_ref, scale_ref, *rest, bits: int,
                   packed: bool, codebook: bool, per_group: bool,
                   group_size: int, n_k: int):
+    del layer_ref                     # read by the codes index map only
     if codebook:
         cb_ref, out_ref, acc_ref = rest
     else:
@@ -131,7 +138,8 @@ def _axllm_kernel(x_ref, codes_ref, scale_ref, *rest, bits: int,
 @functools.partial(jax.jit, static_argnames=(
     "bits", "packed", "group_size", "blocks", "interpret"))
 def axllm_matmul_pallas(x: jax.Array, codes: jax.Array, scale: jax.Array,
-                        codebook: Optional[jax.Array] = None, *,
+                        codebook: Optional[jax.Array] = None,
+                        layer: Optional[jax.Array] = None, *,
                         bits: int = 8, packed: bool = False,
                         group_size: int = 128,
                         blocks=DEFAULT_BLOCKS,
@@ -139,8 +147,12 @@ def axllm_matmul_pallas(x: jax.Array, codes: jax.Array, scale: jax.Array,
     """y[M, N] = x[M, K] @ deq(codes[K, N]); see module docstring.
 
     `scale` must be [1, N] (per_channel/per_tensor broadcast) or [K/g, N]
-    (per_group). `codes` is [K, N] int8, or [K, N//2] uint8 when packed.
+    (per_group). `codes` is [K, N] int8, or [K, N//2] uint8 when packed;
+    with `layer` (an int32 scalar) it is [L, K, N] (or [L, K, N//2]) and
+    layer `layer` is read in place, `scale` being that layer's.
     """
+    if layer is None:
+        codes, layer = codes[None], 0
     m, kdim = x.shape
     n = scale.shape[-1]
     bm, bk, bn = blocks
@@ -158,17 +170,16 @@ def axllm_matmul_pallas(x: jax.Array, codes: jax.Array, scale: jax.Array,
     if packed:
         # the kernel sees each tile's columns in split order (even, odd)
         scale = split_columns(scale, bn)
-    x_spec = pl.BlockSpec((bm, bk), lambda i, j, k: (i, k))
-    if packed:
-        codes_spec = pl.BlockSpec((bk, bn // 2), lambda i, j, k: (k, j))
-    else:
-        codes_spec = pl.BlockSpec((bk, bn), lambda i, j, k: (k, j))
+    # index maps take the prefetched layer ref last; only codes read it
+    x_spec = pl.BlockSpec((bm, bk), lambda i, j, k, l: (i, k))
+    codes_spec = pl.BlockSpec((None, bk, bn // 2 if packed else bn),
+                              lambda i, j, k, l: (l[0], k, j))
     if per_group:
         scale_spec = pl.BlockSpec((bk // group_size, bn),
-                                  lambda i, j, k: (k, j))
+                                  lambda i, j, k, l: (k, j))
     else:
-        scale_spec = pl.BlockSpec((1, bn), lambda i, j, k: (0, j))
-    out_spec = pl.BlockSpec((bm, bn), lambda i, j, k: (i, j))
+        scale_spec = pl.BlockSpec((1, bn), lambda i, j, k, l: (0, j))
+    out_spec = pl.BlockSpec((bm, bn), lambda i, j, k, l: (i, j))
 
     in_specs = [x_spec, codes_spec, scale_spec]
     args = [x, codes, scale]
@@ -183,13 +194,15 @@ def axllm_matmul_pallas(x: jax.Array, codes: jax.Array, scale: jax.Array,
 
     y = pl.pallas_call(
         kernel,
-        grid=(m // bm, n // bn, n_k),
-        in_specs=in_specs,
-        out_specs=out_spec,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(m // bm, n // bn, n_k),
+            in_specs=in_specs,
+            out_specs=out_spec,
+            scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(*args)
+    )(jnp.asarray(layer, jnp.int32).reshape(1), *args)
     return merge_columns(y, bn) if packed else y

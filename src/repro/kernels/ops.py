@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core.quantization import QTensor
+from repro.core.quantization import QTensor, layer_slice
 from repro.dist import sharding as shd
 from repro.kernels import ref as _ref
 from repro.kernels import axllm_matmul as _amm
@@ -117,13 +117,16 @@ def _matmul_call(fn, x2, qt: QTensor, row_parallel: bool):
     Column-parallel weights (codes [K, N] sharded on N) keep x whole and
     return N-sharded output; row-parallel ones (``row_parallel``: wo/down,
     see dist.sharding._param_names) take K-sharded x and all-reduce the
-    partial products. Layouts that do not divide run replicated."""
+    partial products. Layouts that do not divide run replicated. A stack
+    of layers' codes (``qt.layer`` set) keeps its leading axis whole on
+    every shard, and the layer index goes to each shard's kernel."""
     if shd._current() is None:
         return fn(x2, qt)
     codes, scale = qt.codes, qt.scale
+    stack = (None,) * (codes.ndim - 2)
     if row_parallel:
-        cs = _spec(codes.shape, ("mlp", None))
-        ax = cs[0]
+        cs = _spec(codes.shape, stack + ("mlp", None))
+        ax = cs[-2]
         ss = P()
         if qt.granularity == "per_group":
             ss = _spec(scale.shape, ("mlp",) + (None,) * (scale.ndim - 1))
@@ -131,24 +134,28 @@ def _matmul_call(fn, x2, qt: QTensor, row_parallel: bool):
                 cs, ax, ss = P(), None, P()
         xs, out_spec = P(None, ax), P()
     else:
-        cs = _spec(codes.shape, (None, "mlp"))
-        ax = cs[1]
+        cs = _spec(codes.shape, stack + (None, "mlp"))
+        ax = cs[-1]
         ss = P(*((None,) * (scale.ndim - 1)
                  + ((ax,) if scale.shape[-1] > 1 else (None,))))
         xs, out_spec = P(), P(None, ax)
 
-    def local(x_l, codes_l, scale_l):
+    layer = () if qt.layer is None else (jnp.asarray(qt.layer, jnp.int32),)
+
+    def local(x_l, codes_l, scale_l, *layer_l):
         n_l = qt.shape[-1] * codes_l.shape[-1] // codes.shape[-1]
         qt_l = QTensor(codes=codes_l, scale=scale_l, codebook=qt.codebook,
                        bits=qt.bits, mode=qt.mode,
                        granularity=qt.granularity,
                        group_size=qt.group_size, packed=qt.packed,
-                       shape=(codes_l.shape[0], n_l))
+                       shape=(codes_l.shape[-2], n_l),
+                       layer=layer_l[0] if layer_l else None)
         y = fn(x_l, qt_l)
         return y if (not row_parallel or ax is None) \
             else jax.lax.psum(y, ax)
 
-    return _per_shard(local, (x2, codes, scale), (xs, cs, ss), out_spec)
+    return _per_shard(local, (x2, codes, scale, *layer),
+                      (xs, cs, ss) + (P(),) * len(layer), out_spec)
 
 
 # ---------------------------------------------------------------------------
@@ -199,7 +206,10 @@ def pick_blocks(m: int, k: int, n: int, group_size: int = 128,
     silently re-padded on every call. Skinny launches widen bn to 512 (vs
     the 256 default) to keep the MXU fed from the N grid dimension — the
     per-tile VMEM footprint stays far under budget because the x tile
-    shrinks with bm. bk and bn are lane blocks (:func:`_lane_block`).
+    shrinks with bm. Prefill shapes take the largest of 512/256/128 rows
+    that divides m, so a chunk of up to 512 tokens fetches each code tile
+    from HBM once rather than once per 128 rows (about 3 MB of VMEM
+    buffers at 512 rows). bk and bn are lane blocks (:func:`_lane_block`).
 
     >>> pick_blocks(16, 128, 256)       # skinny decode shape: no pad
     (16, 128, 256, 0)
@@ -207,9 +217,13 @@ def pick_blocks(m: int, k: int, n: int, group_size: int = 128,
     (8, 128, 256, 7)
     >>> pick_blocks(4, 768, 2048)       # repro-100m decode, 4 slots
     (8, 256, 512, 4)
+    >>> pick_blocks(512, 4096, 12800)   # a 512-token prefill chunk
+    (512, 512, 256, 0)
+    >>> pick_blocks(200, 768, 768)      # ragged prefill pads to 128 rows
+    (128, 256, 256, 56)
     """
     if m >= 128:
-        bm = 128
+        bm = next((b for b in (512, 256, 128) if m % b == 0), 128)
     else:
         bm = next((b for b in _amm.SKINNY_BM if m % b == 0), 8)
     bk = _lane_block(k, 512)
@@ -231,8 +245,14 @@ def axllm_matmul(x: jax.Array, qt: QTensor, *, impl: str = "auto",
     :func:`reuse_matmul` for the stats-bearing entry point).
     ``row_parallel`` says the weight is placed contraction-sharded under a
     mesh (see :func:`_matmul_call`); it changes no result.
+
+    A ``qt`` with ``layer`` set holds a scanned model's whole [L, K, N]
+    codes: the Pallas kernel reads that layer's tiles in place, per shard
+    too; the oracle and the reuse kernel take ``codes[layer]`` first.
     """
     out_dtype = out_dtype or x.dtype
+    if not _use_pallas(impl):
+        qt = layer_slice(qt)
     if impl in REUSE_IMPLS:
         y, _ = reuse_matmul(x, qt, impl=impl, out_dtype=out_dtype,
                             row_parallel=row_parallel)
@@ -252,7 +272,7 @@ def axllm_matmul(x: jax.Array, qt: QTensor, *, impl: str = "auto",
         if pad_m:
             x2 = jnp.pad(x2, ((0, pad_m), (0, 0)))
         y = _amm.axllm_matmul_pallas(
-            x2, qt.codes, _kernel_scale(qt), resolve_codebook(qt),
+            x2, qt.codes, _kernel_scale(qt), resolve_codebook(qt), qt.layer,
             bits=qt.bits, packed=qt.packed, group_size=qt.group_size,
             blocks=(bm, bk, bn), interpret=_interpret(impl))
         return y[:m]

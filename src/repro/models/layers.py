@@ -8,12 +8,15 @@ conversion (quantize_tree) and the sharding rules apply uniformly.
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.axllm_linear import linear
+from repro.core.quantization import QTensor
 from repro.dist.sharding import shard as _shard
 
 
@@ -36,6 +39,35 @@ def maybe_scan(body, carry, xs, use_scan: bool = True):
     else:
         ys = None
     return carry, ys
+
+
+def scan_layer_params(body, carry, layers, xs, use_scan: bool = True):
+    """:func:`maybe_scan` of ``body(carry, (lp, *xs_i))`` over the stacked
+    layer params ``layers`` and the per-layer tuple ``xs``, with every
+    QTensor's codes left whole.
+
+    Scanning a stacked QTensor would hand each layer a [K, N] slice of its
+    codes, which XLA materializes before a Pallas call (it cannot pass part
+    of a buffer to one). Here the codes are closed over instead, everything
+    else is scanned, and ``lp`` carries QTensors with the whole codes and
+    ``layer`` set to this layer's index (an int32 scalar in the scan, a
+    concrete index unrolled); see ``ops.axllm_matmul``."""
+    leaves, tree = jax.tree_util.tree_flatten(
+        layers, is_leaf=lambda a: isinstance(a, QTensor))
+    codes = [a.codes if isinstance(a, QTensor) else None for a in leaves]
+    rest = [dataclasses.replace(a, codes=None) if isinstance(a, QTensor)
+            else a for a in leaves]
+    n = jax.tree_util.tree_leaves(layers)[0].shape[0]
+    index = jnp.arange(n, dtype=jnp.int32) if use_scan else np.arange(n)
+
+    def step(c, inp):
+        i, rest_i, *xs_i = inp
+        lp = jax.tree_util.tree_unflatten(tree, [
+            a if w is None else dataclasses.replace(a, codes=w, layer=i)
+            for a, w in zip(rest_i, codes)])
+        return body(c, (lp, *xs_i))
+
+    return maybe_scan(step, carry, (index, rest, *xs), use_scan)
 
 
 def truncated_normal(rng, shape, std, dtype=jnp.float32):

@@ -198,12 +198,13 @@ def prefill(params, tokens, cfg, cache, impl: str = "auto", lengths=None,
         x2 = x1 + _ffn_fwd(lp["ffn"], h2, cfg, impl)
         return shard(x2, "batch", "seq"), new_lc
 
-    xs = (params["layers"], _cache_xs(cache))
+    xs = (_cache_xs(cache),)
     if prefix_kv is not None:
         xs = xs + (prefix_kv,)
     if adapters is not None:
         xs = xs + (adapters,)
-    x, new_kv = L.maybe_scan(body, x, xs, cfg.scan_layers)
+    x, new_kv = L.scan_layer_params(body, x, params["layers"], xs,
+                                    cfg.scan_layers)
     if lengths is None:
         x = x[:, -1:]
         pos = jnp.full((b,), s, jnp.int32)
@@ -254,10 +255,11 @@ def decode_step(params, token, cfg, cache, impl: str = "auto",
         x2 = x1 + _ffn_fwd(lp["ffn"], h2, cfg, impl)
         return x2, new_lc
 
-    xs = (params["layers"], _cache_xs(cache))
+    xs = (_cache_xs(cache),)
     if adapters is not None:
         xs = xs + (adapters,)
-    x, new_kv = L.maybe_scan(body, x, xs, cfg.scan_layers)
+    x, new_kv = L.scan_layer_params(body, x, params["layers"], xs,
+                                    cfg.scan_layers)
     x = L.norm_fwd(params["final_norm"], x, cfg.norm_eps)
     logits = L.head_fwd(params["embed"], x, cfg, impl=impl)[:, 0]
     new_cache = dict(new_kv)

@@ -1,6 +1,8 @@
 """Pallas kernel validation: interpret-mode sweeps vs the pure-jnp oracles
 (shape x dtype x quant-mode grids per kernel)."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -89,6 +91,41 @@ def test_lora_matmul_matches_ref():
                                rtol=2e-5, atol=2e-4)
 
 
+# the four code layouts of the serving path, read in place from a stack of
+# layers: the same tiles in the same order, so the same bits
+STACKED_CONFIGS = [
+    QuantConfig(8, "affine", "per_channel"),
+    QuantConfig(8, "affine", "per_group", group_size=128),
+    QuantConfig(4, "affine", "per_channel", pack=True),
+    QuantConfig(4, "codebook", "per_channel", pack=True),
+]
+
+
+@pytest.mark.parametrize("layer", [0, 2], ids=["first", "last"])
+@pytest.mark.parametrize("qcfg", STACKED_CONFIGS,
+                         ids=lambda c: f"{c.bits}b-{c.mode}-{c.granularity}")
+def test_axllm_matmul_reads_layer_in_place(qcfg, layer):
+    from repro.core.quantization import layer_slice, resolve_codebook
+    from repro.kernels.axllm_matmul import axllm_matmul_pallas
+    rng = np.random.default_rng(5)
+    m, k, n = 8, 512, 256
+    x = _rand(rng, (m, k))
+    stack = quantize(_rand(rng, (3, k, n)), qcfg)          # [L, K, N] codes
+    one = layer_slice(dataclasses.replace(
+        stack, scale=stack.scale[layer], layer=layer))     # codes[layer]
+    scale = ops._kernel_scale(one)
+    kw = dict(bits=qcfg.bits, packed=stack.packed,
+              group_size=qcfg.group_size,
+              blocks=ops.pick_blocks(m, k, n, qcfg.group_size,
+                                     qcfg.granularity == "per_group")[:3],
+              interpret=True)
+    cb = resolve_codebook(stack)
+    want = axllm_matmul_pallas(x, one.codes, scale, cb, **kw)
+    got = axllm_matmul_pallas(x, stack.codes, scale, cb,
+                              jnp.asarray(layer, jnp.int32), **kw)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 # ---------------------------------------------------------------------------
 # Decode-shape block table (pad decision lives in the table, not the call)
 # ---------------------------------------------------------------------------
@@ -105,7 +142,7 @@ def test_pick_blocks_widens_bn_for_skinny_m():
     bm, _, bn, _ = ops.pick_blocks(8, 1024, 1024)
     assert bm == 8 and bn == 512          # decode shape: wide N tiles
     bm, _, bn, _ = ops.pick_blocks(256, 1024, 1024)
-    assert bm == 128 and bn == 256        # prefill shape: default tiling
+    assert bm == 256 and bn == 256        # prefill shape: one row block
 
 
 def test_pick_blocks_divisor_safe():

@@ -11,6 +11,8 @@ one process at a time may load the TPU library, and every test worker
 imports this file.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -82,9 +84,10 @@ def test_paged_decode_attention_compiles(one_chip, kv):
 
 
 # (m, K, N): decode m = slots, prefill m = 4 x 32; one tensor-parallel
-# shard of a column-parallel projection (N / 4 = 192 lanes)
+# shard of a column-parallel projection (N / 4 = 192 lanes); a 512-token
+# prefill chunk in one row block
 MATMULS = [(B, D, D), (B, D, HK * HD), (B, D, DFF), (B, DFF, D),
-           (128, DFF, D), (B, D, D // 4)]
+           (128, DFF, D), (B, D, D // 4), (512, DFF, D)]
 
 
 def _blocks(m, k, n):
@@ -111,7 +114,7 @@ def test_axllm_matmul_nf4_packed_compiles(one_chip, m, k, n):
 
 
 @pytest.mark.parametrize("count", [False, True], ids=["serve", "counting"])
-@pytest.mark.parametrize("m,k,n", [(B, D, D), (128, D, D)])
+@pytest.mark.parametrize("m,k,n", [(B, D, D), (128, D, D), (512, D, D)])
 def test_reuse_matmul_compiles(one_chip, m, k, n, count):
     from repro.kernels.reuse_matmul import reuse_matmul_pallas
     mp = m + ops.pick_blocks(m, k, n)[3]
@@ -127,3 +130,71 @@ def test_flash_attention_compiles(one_chip, s):
     _compile(one_chip, flash_attention_pallas,
              ((4, s, H, HD), jnp.bfloat16), ((4, s, HK, HD), jnp.bfloat16),
              ((4, s, HK, HD), jnp.bfloat16))
+
+
+def _defs(text):
+    """HLO instruction name -> (result type, opcode, operand text)."""
+    return {m[1]: (m[2], m[3], m[4]) for m in re.finditer(
+        r"^\s*(?:ROOT )?%([\w.\-]+) = (\S+) ([\w\-]+)\(([^)]*)\)", text,
+        re.M)}
+
+
+def _weight_kernel_code_copies(one_chip, program):
+    """Compile ``program(params, cache)`` of the quantized repro-100m model
+    for one described v5e chip; return the number of axllm_matmul_pallas
+    calls and the s8 operands among them that are a copy of the codes.
+
+    A parameter or tuple element is the codes as the program holds them,
+    and a bitcast of one (the [1, K, N] view of a standalone weight) is no
+    copy either; anything else must still be the whole [n_layers, K, N]
+    stack (the compiler may prefetch a small one into fast memory)."""
+    from repro.core.axllm_linear import deploy_quantize
+    from repro.core.quantization import QuantConfig
+    from repro.models import transformer as T
+
+    params = jax.eval_shape(lambda: deploy_quantize(
+        T.init_params(jax.random.PRNGKey(0), CONFIG), QuantConfig(bits=8)))
+    cache = jax.eval_shape(lambda: T.init_cache(CONFIG, B, S))
+    put = lambda t: jax.tree_util.tree_map(             # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    text = jax.jit(program).lower(put(params), put(cache)).compile().as_text()
+    defs = _defs(text)
+    calls = re.findall(r"^\s*(?:ROOT )?%axllm_matmul_pallas[.\d]* = \S+ "
+                       r"custom-call\(([^)]*)\)", text, re.M)
+    stack = f"s8[{CONFIG.n_layers},"
+    copies = []
+    for operands in calls:
+        for name in re.findall(r"%([\w.\-]+)", operands):
+            ty, op, args = defs[name]
+            while op == "bitcast":
+                ty, op, args = defs[re.search(r"%([\w.\-]+)", args)[1]]
+            if ty.startswith("s8[") and not ty.startswith(stack) \
+                    and op not in ("parameter", "get-tuple-element"):
+                copies.append((name, ty, op))
+    return len(calls), copies
+
+
+def test_decode_step_reads_codes_in_place(one_chip):
+    """The quantized decode step hands every weight kernel the whole
+    [n_layers, K, N] stack of int8 codes: no per-layer copy of the codes
+    (an s8 [K, N] fusion or slice) feeds an axllm_matmul_pallas call."""
+    from repro.models import transformer as T
+
+    token = jnp.zeros((B,), jnp.int32)
+    n, copies = _weight_kernel_code_copies(one_chip, lambda p, c: (
+        T.decode_step(p, token, CONFIG, c, impl="pallas")))
+    assert n >= 7                            # q, k, v, o, gate, up, down
+    assert not copies, copies
+
+
+def test_prefill_reads_codes_in_place(one_chip):
+    """The same for a 512-token prefill (4 rows x 128), whose weight
+    kernels take the whole chunk in one row block."""
+    from repro.models import transformer as T
+
+    tokens = jnp.zeros((B, S // 2), jnp.int32)
+    n, copies = _weight_kernel_code_copies(one_chip, lambda p, c: (
+        T.prefill(p, tokens, CONFIG, c, impl="pallas")))
+    assert n >= 7
+    assert not copies, copies
