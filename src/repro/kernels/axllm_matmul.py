@@ -22,11 +22,17 @@ Layout & tiling
 index map reads layer ``l``'s tiles straight out of the stack, so no
 per-layer [K, N] copy is made before the call.
 
-Grid = (M/bm, N/bn, K/bk), K innermost ("arbitrary" semantics) so the f32
-accumulator tile persists in VMEM scratch across K steps. MXU-aligned block
-defaults (bm, bk, bn) = (128, 512, 256); VMEM footprint ≈ x 128·512·4 +
-codes 512·256 + acc 128·256·4 ≈ 0.5 MB — far under the ~16 MB v5e budget,
-leaving room for double buffering.
+Grid = (M/bm, ⌈N/bn⌉, ⌈K/bk⌉), K innermost ("arbitrary" semantics) so the
+f32 accumulator tile persists in VMEM scratch across K steps. Unpacked
+codes may leave a ragged last block on N, and on K unless the scales are
+per group, so a width with no factor of 256 (GLM-4's d_ff 13696 = 107·128)
+still takes 512-lane tiles: the kernel zeroes x's columns past K, so
+whatever the out-of-bounds code rows hold multiplies zero, and output
+columns past N are dropped by the out-of-bounds write. Dims that divide
+run with no mask. MXU-aligned block defaults (bm, bk, bn) = (128, 512,
+256); VMEM footprint ≈ x 128·512·4 + codes 512·256 + acc 128·256·4 ≈
+0.5 MB — far under the ~16 MB v5e budget, leaving room for double
+buffering.
 """
 
 from __future__ import annotations
@@ -106,7 +112,7 @@ def _dot(x, w):
 
 def _axllm_kernel(layer_ref, x_ref, codes_ref, scale_ref, *rest, bits: int,
                   packed: bool, codebook: bool, per_group: bool,
-                  group_size: int, n_k: int):
+                  group_size: int, n_k: int, k_ragged: int):
     del layer_ref                     # read by the codes index map only
     if codebook:
         cb_ref, out_ref, acc_ref = rest
@@ -126,7 +132,13 @@ def _axllm_kernel(layer_ref, x_ref, codes_ref, scale_ref, *rest, bits: int,
         bk, bn = w.shape
         w = (w.astype(jnp.float32).reshape(bk // group_size, group_size, bn)
              * scale_ref[...][:, None, :]).reshape(bk, bn)
-    acc_ref[...] += _dot(x_ref[...], w)
+    x = x_ref[...]
+    if k_ragged:
+        # K (= k_ragged) has a ragged last block: zero x past it with a
+        # select, not a multiply, as out-of-bounds reads may be NaN bits
+        col = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+        x = jnp.where(col < k_ragged - k * x.shape[1], x, jnp.zeros_like(x))
+    acc_ref[...] += _dot(x, w)
 
     @pl.when(k == n_k - 1)
     def _flush():
@@ -149,7 +161,9 @@ def axllm_matmul_pallas(x: jax.Array, codes: jax.Array, scale: jax.Array,
     `scale` must be [1, N] (per_channel/per_tensor broadcast) or [K/g, N]
     (per_group). `codes` is [K, N] int8, or [K, N//2] uint8 when packed;
     with `layer` (an int32 scalar) it is [L, K, N] (or [L, K, N//2]) and
-    layer `layer` is read in place, `scale` being that layer's.
+    layer `layer` is read in place, `scale` being that layer's. bm must
+    divide M; bk and bn may leave a ragged last block unless the codes are
+    packed or the scales per group (bk must then divide K).
     """
     if layer is None:
         codes, layer = codes[None], 0
@@ -159,11 +173,11 @@ def axllm_matmul_pallas(x: jax.Array, codes: jax.Array, scale: jax.Array,
     bm = min(bm, m)
     bk = min(bk, kdim)
     bn = min(bn, n)
-    if m % bm or kdim % bk or n % bn:
+    per_group = scale.shape[0] > 1
+    if m % bm or (packed and n % bn) or ((packed or per_group) and kdim % bk):
         raise ValueError(f"shape ({m},{kdim},{n}) not divisible by blocks "
                          f"({bm},{bk},{bn})")
-    n_k = kdim // bk
-    per_group = scale.shape[0] > 1
+    n_k = pl.cdiv(kdim, bk)
     if per_group and bk % group_size:
         raise ValueError("per_group requires group_size | bk")
 
@@ -190,13 +204,14 @@ def axllm_matmul_pallas(x: jax.Array, codes: jax.Array, scale: jax.Array,
     kernel = functools.partial(
         _axllm_kernel, bits=bits, packed=packed,
         codebook=codebook is not None, per_group=per_group,
-        group_size=group_size, n_k=n_k)
+        group_size=group_size, n_k=n_k,
+        k_ragged=kdim if kdim % bk else 0)
 
     y = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(m // bm, n // bn, n_k),
+            grid=(m // bm, pl.cdiv(n, bn), n_k),
             in_specs=in_specs,
             out_specs=out_spec,
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)]),

@@ -186,17 +186,23 @@ def _divisor_block(dim: int, target: int) -> int:
     return dim
 
 
-def _lane_block(dim: int, target: int) -> int:
-    """Largest power-of-two block in [128, target] that divides dim, else
-    the whole dim: Mosaic takes a lane-dim block that is a multiple of 128
-    or spans the array (tensor-parallel shards such as 768 / 4 = 192 land
-    on the second case)."""
-    return next((b for b in (512, 256, 128) if b <= target and dim % b == 0),
-                dim)
+def _lane_block(dim: int, target: int, ragged: bool) -> int:
+    """Lane-dim block: the largest of 512/256 (<= target) that divides dim;
+    else, with ``ragged``, the whole dim when it fits in one block and the
+    target otherwise, leaving a ragged last block for the weight kernel to
+    mask; else 128 when it divides, else the whole dim. Mosaic takes a
+    lane-dim block that is a multiple of 128 or spans the array
+    (tensor-parallel shards such as 768 / 4 = 192 take the whole dim)."""
+    b = next((b for b in (512, 256) if b <= target and dim % b == 0), None)
+    if b is not None:
+        return b
+    if ragged:
+        return dim if dim <= target else target
+    return 128 if dim % 128 == 0 else dim
 
 
 def pick_blocks(m: int, k: int, n: int, group_size: int = 128,
-                per_group: bool = False):
+                per_group: bool = False, ragged: bool = True):
     """Block-size table for the fused dequant-matmul and the reuse (LUT)
     matmul: (bm, bk, bn, pad_m).
 
@@ -209,7 +215,12 @@ def pick_blocks(m: int, k: int, n: int, group_size: int = 128,
     shrinks with bm. Prefill shapes take the largest of 512/256/128 rows
     that divides m, so a chunk of up to 512 tokens fetches each code tile
     from HBM once rather than once per 128 rows (about 3 MB of VMEM
-    buffers at 512 rows). bk and bn are lane blocks (:func:`_lane_block`).
+    buffers at 512 rows). bk and bn are lane blocks (:func:`_lane_block`):
+    with ``ragged`` (the weight kernel on unpacked codes) a dim with no
+    factor of 256, such as GLM-4's d_ff 13696 = 107·128, still takes the
+    full-width tile and a masked last block; without it (the reuse kernel,
+    packed codes) every block divides its dim. Per-group scales keep bk a
+    divisor of k.
 
     >>> pick_blocks(16, 128, 256)       # skinny decode shape: no pad
     (16, 128, 256, 0)
@@ -221,13 +232,17 @@ def pick_blocks(m: int, k: int, n: int, group_size: int = 128,
     (512, 512, 256, 0)
     >>> pick_blocks(200, 768, 768)      # ragged prefill pads to 128 rows
     (128, 256, 256, 56)
+    >>> pick_blocks(8, 4096, 13696)     # GLM-4 gate/up in decode
+    (8, 512, 512, 0)
+    >>> pick_blocks(8, 13696, 4096, ragged=False)
+    (8, 128, 512, 0)
     """
     if m >= 128:
         bm = next((b for b in (512, 256, 128) if m % b == 0), 128)
     else:
         bm = next((b for b in _amm.SKINNY_BM if m % b == 0), 8)
-    bk = _lane_block(k, 512)
-    bn = _lane_block(n, 512 if bm <= 32 else 256)
+    bk = _lane_block(k, 512, ragged and not per_group)
+    bn = _lane_block(n, 512 if bm <= 32 else 256, ragged)
     if per_group:
         g_bk = (bk // group_size) * group_size
         if g_bk <= 0 or k % g_bk:
@@ -268,7 +283,8 @@ def axllm_matmul(x: jax.Array, qt: QTensor, *, impl: str = "auto",
         kdim, n = qt.shape[-2], qt.shape[-1]
         m = x2.shape[0]
         bm, bk, bn, pad_m = pick_blocks(m, kdim, n, qt.group_size,
-                                        qt.granularity == "per_group")
+                                        qt.granularity == "per_group",
+                                        ragged=not qt.packed)
         if pad_m:
             x2 = jnp.pad(x2, ((0, pad_m), (0, 0)))
         y = _amm.axllm_matmul_pallas(
@@ -316,7 +332,7 @@ def reuse_matmul(x: jax.Array, qt: QTensor, *, impl: str = "auto",
         mults = None
         if with_stats:
             bn = pick_blocks(x2.shape[0], kdim, n, qt.group_size,
-                             qt.granularity == "per_group")[2]
+                             qt.granularity == "per_group", ragged=False)[2]
             mults = _ref.reuse_mult_count(qt, bn)
         return y.reshape(*lead, n).astype(out_dtype), mults
 
@@ -328,7 +344,8 @@ def reuse_matmul(x: jax.Array, qt: QTensor, *, impl: str = "auto",
         m = x2.shape[0]
         bm, bk, bn, pad_m = pick_blocks(m, qt.shape[-2], qt.shape[-1],
                                         qt.group_size,
-                                        qt.granularity == "per_group")
+                                        qt.granularity == "per_group",
+                                        ragged=False)
         if pad_m:
             x2 = jnp.pad(x2, ((0, pad_m), (0, 0)))
         y, c = _rmm.reuse_matmul_pallas(

@@ -164,8 +164,11 @@ Scheduler contract
   `jax.profiler.TraceAnnotation` (`engine.step`, `engine.admit`,
   `engine.prefill`, `engine.sync`, `engine.prefill_sync`, `engine.plan`,
   `engine.decode`, `engine.emit`, `engine.preempt`) whose keyword stats
-  carry the work done (`rows`, `tokens`, `hit`, `steps`, `cow`) and the
-  request ids (`rids`, space-separated). Every jitted program is named
+  carry the work done (`rows`, `tokens`, `hit`, `steps`, `cow`), the
+  cached positions a prefill wave's rows attend (`prefix`), the keys a
+  decode dispatch's rows attend over its steps (`keys`, from the
+  positions and budgets the host holds at dispatch) and the request ids
+  (`rids`, space-separated). Every jitted program is named
   (`jit_prefill`, `jit_paged_prefill`, `jit_decode_chunk`,
   `jit_copy_blocks`, `jit_write_wave`, `jit_sample_tokens`,
   `jit_spec_draft`, `jit_spec_verify`). Without a profiler session a
@@ -394,6 +397,17 @@ def _rids(reqs) -> str:
     """Request ids of a dispatch as one span stat (spaces: a comma would
     split the profiler's stat encoding)."""
     return " ".join(str(r.rid) for r in reqs)
+
+
+def _attended(pos: int, steps: int) -> int:
+    """Keys one row attends over ``steps`` decode steps from cache
+    position ``pos``: step j writes position pos + j and attends the
+    pos + j + 1 keys up to it.
+
+    >>> _attended(10, 3)            # 11 + 12 + 13
+    36
+    """
+    return steps * (2 * pos + steps + 1) // 2
 
 
 def _pow2_bucket(n: int, lo: int, hi: int) -> int:
@@ -1173,7 +1187,8 @@ class ServeEngine:
         fn = self._get_prefill(wb, pl)
         try:
             with TraceAnnotation("engine.prefill", rows=w, tokens=sum(lens),
-                                 hit=0, rids=_rids(group), kind="first"):
+                                 hit=0, prefix=0, rids=_rids(group),
+                                 kind="first"):
                 if self.fault_hook is not None:
                     self.fault_hook("prefill")
                 if self.registry is not None:
@@ -1223,7 +1238,7 @@ class ServeEngine:
                 # exactly the target's positions. Same jitted fn — params
                 # are jit arguments, the draft's structure traces once.
                 with TraceAnnotation("engine.prefill", rows=w,
-                                     tokens=sum(lens), hit=0,
+                                     tokens=sum(lens), hit=0, prefix=0,
                                      rids=_rids(group), kind="draft"):
                     if self.registry is not None:
                         _, dwave = fn(self.draft_params, jnp.asarray(toks),
@@ -1374,8 +1389,8 @@ class ServeEngine:
         fn = self._get_paged_prefill(wb, pl, npb)
         try:
             with TraceAnnotation("engine.prefill", rows=w, tokens=sum(takes),
-                                 hit=sum(hit_toks), rids=_rids(admitted),
-                                 kind="first"):
+                                 hit=sum(hit_toks), prefix=sum(hit_toks),
+                                 rids=_rids(admitted), kind="first"):
                 logits, self.cache = self._dispatch_paged_prefill(
                     fn, toks, lengths, prefix_len, pbt, sbt, aidx)
         except Exception:
@@ -1551,6 +1566,7 @@ class ServeEngine:
         try:
             with TraceAnnotation("engine.prefill", rows=len(ran),
                                  tokens=sum(t for *_, t in ran), hit=0,
+                                 prefix=int(prefix_len.sum()),
                                  rids=_rids(r for _, r, _, _ in ran),
                                  kind="continue"):
                 logits, self.cache = self._dispatch_paged_prefill(
@@ -1619,6 +1635,7 @@ class ServeEngine:
         fn = self._get_prefill(wb, pl)
         with TraceAnnotation("engine.prefill", rows=len(keep),
                              tokens=int(lengths[:len(keep)].sum()), hit=0,
+                             prefix=0,
                              rids=_rids(admitted[i] for i, _ in keep),
                              kind="draft"):
             if self.registry is not None:
@@ -1865,9 +1882,10 @@ class ServeEngine:
             plan.set_metadata(cow=len(cow))
         draft_fn, verify_fn = self._get_spec_fns(k)
         rids = _rids(self.slots[i] for i in active)
+        keys = sum(_attended(positions[i], k + 1) for i in active)
         if k:
             with TraceAnnotation("engine.decode", phase="draft", steps=k + 1,
-                                 rows=len(active), rids=rids):
+                                 rows=len(active), keys=keys, rids=rids):
                 if self.fault_hook is not None:
                     self.fault_hook("draft")
                 if self.registry is not None:
@@ -1885,7 +1903,7 @@ class ServeEngine:
         else:
             drafts = np.zeros((0, self.n_slots), np.int32)
         with TraceAnnotation("engine.decode", phase="verify", steps=k + 1,
-                             rows=len(active), rids=rids):
+                             rows=len(active), keys=keys, rids=rids):
             if self.fault_hook is not None:
                 self.fault_hook("verify")
             if self.registry is not None:
@@ -1974,6 +1992,14 @@ class ServeEngine:
         return max(1, min(self.decode_chunk, remaining,
                           max_n if max_n is not None else remaining))
 
+    def _window(self, r: Request, n: int):
+        """(cache position of ``r``'s next decode input, steps of an
+        n-step chunk that ``r`` can use before its budget or the cache
+        ends it; at least 1, the step the scan runs anyway)."""
+        pos = len(r.prompt) + len(r.tokens) - 1
+        return pos, max(1, min(n, r.max_new - len(r.tokens),
+                               self.max_len - pos))
+
     def _step(self, max_n: Optional[int] = None) -> bool:
         if self.prefill_budget is not None:
             # per-STEP prefill-token ledger: first chunks and
@@ -1984,6 +2010,11 @@ class ServeEngine:
         self._admit()
         active = [i for i, s in enumerate(self.slots) if s is not None]
         while not active and self.queue:
+            if self.prefill_budget is not None and self._prefill_progress:
+                # the step's budget went to prefills that finished on their
+                # first token: that is this step's work, and the next step
+                # admits the queue with a fresh budget
+                return True
             # a whole wave can finish at prefill (EOS/max_new on the first
             # token); keep admitting so queued work is never stranded
             before = (len(self.queue), len(self.finished),
@@ -2033,12 +2064,8 @@ class ServeEngine:
                 while True:
                     need = 0
                     for i in decode_active:
-                        r = self.slots[i]
-                        pos0 = len(r.prompt) + len(r.tokens) - 1
-                        rem = min(r.max_new - len(r.tokens),
-                                  self.max_len - pos0)
-                        a, c = self.pager.plan_decode(i, pos0,
-                                                      max(1, min(n, rem)))
+                        a, c = self.pager.plan_decode(
+                            i, *self._window(self.slots[i], n))
                         need += a + c
                     if self.pager.can_allocate(need):
                         break
@@ -2056,12 +2083,14 @@ class ServeEngine:
             gen = np.zeros((self.n_slots,), np.int32)
             budget = np.zeros((self.n_slots,), np.int32)
             stop = np.ones((self.n_slots,), bool)
+            keys = 0
             for i in decode_active:
                 r = self.slots[i]
                 last[i] = r.tokens[-1]
                 gen[i] = len(r.tokens)
                 budget[i] = r.max_new
                 stop[i] = False
+                keys += _attended(*self._window(r, n))
             if self.paged:
                 # make every active slot's write window [pos, pos+n) backed by
                 # uniquely owned blocks: append fresh blocks past the table end
@@ -2070,12 +2099,9 @@ class ServeEngine:
                 # re-run after a decode-phase fault is a no-op (idempotent).
                 pos_host = np.zeros((self.n_slots,), np.int32)
                 for i in decode_active:
-                    r = self.slots[i]
-                    pos0 = len(r.prompt) + len(r.tokens) - 1
+                    pos0, steps = self._window(self.slots[i], n)
                     pos_host[i] = pos0
-                    rem = min(r.max_new - len(r.tokens), self.max_len - pos0)
-                    cow += self.pager.prepare_decode(i, pos0,
-                                                     max(1, min(n, rem)))
+                    cow += self.pager.prepare_decode(i, pos0, steps)
                 if cow:
                     # pad to a power-of-two count (trash onto trash) so the
                     # jitted copier compiles once per bucket, not per count
@@ -2103,7 +2129,7 @@ class ServeEngine:
             plan.set_metadata(cow=len(cow))
         fn = self._get_chunk_fn(n)
         with TraceAnnotation("engine.decode", steps=n,
-                             rows=len(decode_active),
+                             rows=len(decode_active), keys=keys,
                              rids=_rids(self.slots[i] for i in decode_active)):
             if self.fault_hook is not None:
                 # after the (idempotent) pager commit, before the dispatch:

@@ -165,3 +165,44 @@ def test_engine_spans_match_the_engine(params, mode, tmp_path):
         assert r.t_submit <= r.t_admit <= r.t_first
         wait += r.t_admit - r.t_submit
     assert eng.stats.queue_wait_s == pytest.approx(wait)
+
+
+def test_decode_keys_and_prefill_prefix_on_a_session(params, tmp_path):
+    """A two-turn session whose second turn hits the prefix index: each
+    ``engine.prefill`` carries the cached positions its rows attend
+    (``prefix``: its chunk's start) and each ``engine.decode`` the keys
+    its rows attend over its steps (``keys``), both as the host knows
+    them at dispatch."""
+    eng = ServeEngine(CFG, params, n_slots=2, max_len=64, decode_chunk=2,
+                      paged=True, kv_block_size=8, prefill_budget=16)
+    first = np.arange(21) + 5
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        eng.submit(first, 6)
+        while eng.step():
+            pass
+        turn = eng.finished[0]
+        second = np.concatenate([first, turn.tokens, np.arange(10) + 200])
+        eng.submit(second, 5)
+        while eng.step():
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    spans, _ = _read(tmp_path)
+    prefill = [st for n, _, _, st in spans if n == "engine.prefill"]
+    decode = [st for n, _, _, st in spans if n == "engine.decode"]
+    # turn 1: chunks of 16 and 5 tokens, the second after 16 of its own;
+    # turn 2: 24 positions (three whole blocks of turn 1's prompt and
+    # reply) from the index, then its 13 new tokens in one chunk
+    assert [(st["kind"], st["tokens"], st["hit"], st["prefix"])
+            for st in prefill] == [("first", 16, 0, 0),
+                                   ("continue", 5, 0, 16),
+                                   ("first", 13, 24, 24)]
+    # token g >= 1 of a turn is decoded from position len(prompt) + g - 1
+    # and attends the len(prompt) + g keys up to it
+    want = sum(len(r.prompt) + g for r in eng.finished
+               for g in range(1, len(r.tokens)))
+    assert sum(st["keys"] for st in decode) == want
+    assert [len(r.tokens) for r in eng.finished] == [6, 5]

@@ -146,10 +146,83 @@ def test_pick_blocks_widens_bn_for_skinny_m():
 
 
 def test_pick_blocks_divisor_safe():
-    """Shapes the old min(512, k) rule would crash on (k % bk != 0)."""
+    """Shapes the old min(512, k) rule would crash on (k % bk != 0): every
+    block divides its dim where the kernel takes no ragged block (the
+    reuse kernel, packed codes); the weight kernel's blocks are lane-legal
+    (a multiple of 128 or the whole dim), its ragged tails masked."""
     for m, k, n in [(8, 384, 320), (16, 768, 640), (2, 96, 48)]:
-        bm, bk, bn, pad_m = ops.pick_blocks(m, k, n)
+        bm, bk, bn, pad_m = ops.pick_blocks(m, k, n, ragged=False)
         assert k % bk == 0 and n % bn == 0 and (m + pad_m) % bm == 0
+        bm, bk, bn, pad_m = ops.pick_blocks(m, k, n)
+        assert (m + pad_m) % bm == 0
+        for dim, b in ((k, bk), (n, bn)):
+            assert b == dim or b % 128 == 0
+
+
+# Granite-3.0-8B (d4096, 32/8 heads, d_ff 12800) keeps the blocks it was
+# measured with; GLM-4-9B's d_ff 13696 = 107·128 takes the same 512-lane
+# decode tiles with a ragged last block, where it fell to 128 lanes
+@pytest.mark.parametrize("shape,blocks", [
+    ((512, 4096, 12800), (512, 512, 256, 0)),
+    ((8, 4096, 12800), (8, 512, 512, 0)),
+    ((8, 12800, 4096), (8, 512, 512, 0)),
+    ((8, 4096, 1024), (8, 512, 512, 0)),
+    ((128, 4096, 4096), (128, 512, 256, 0)),
+    ((8, 4096, 49408), (8, 512, 256, 0)),
+    ((8, 4096, 13696), (8, 512, 512, 0)),
+    ((8, 13696, 4096), (8, 512, 512, 0)),
+    ((128, 4096, 13696), (128, 512, 256, 0)),
+    ((128, 13696, 4096), (128, 512, 256, 0)),
+], ids=["granite-prefill-ffn", "granite-gate", "granite-down", "granite-kv",
+        "granite-prefill-wq", "granite-head", "glm-gate", "glm-down",
+        "glm-prefill-gate", "glm-prefill-down"])
+def test_pick_blocks_model_shapes(shape, blocks):
+    assert ops.pick_blocks(*shape) == blocks
+
+
+@pytest.mark.parametrize("m", [3, 8, 128])
+@pytest.mark.parametrize("k,n", [(384, 640), (640, 384), (640, 640)])
+@pytest.mark.parametrize("stacked", [False, True], ids=["2d", "stacked"])
+def test_axllm_matmul_ragged_blocks(m, k, n, stacked):
+    """Dims that are odd multiples of 128 (GLM-4's d_ff 13696 = 107·128,
+    scaled down) run on 512-lane tiles with a ragged last block on K and
+    N: x's columns past K are zeroed and the columns past N dropped, so
+    the result matches the jnp oracle, for 2-D and stacked codes."""
+    rng = np.random.default_rng(9)
+    x = _rand(rng, (m, k))
+    w = _rand(rng, (3, k, n) if stacked else (k, n))
+    qt = quantize(w, QUANT_CONFIGS[0])
+    if stacked:
+        qt = dataclasses.replace(qt, scale=qt.scale[2], layer=2)
+    bm, bk, bn, _ = ops.pick_blocks(m, k, n)
+    assert k % bk or n % bn                 # a ragged last block
+    y_ref = ops.axllm_matmul(x, qt, impl="ref")
+    y_pal = ops.axllm_matmul(x, qt, impl="pallas_interpret")
+    assert np.isfinite(np.asarray(y_pal)).all()
+    np.testing.assert_allclose(np.asarray(y_pal), np.asarray(y_ref),
+                               rtol=2e-5, atol=2e-4)
+
+
+@pytest.mark.parametrize("row_parallel", [False, True],
+                         ids=["column", "row"])
+def test_axllm_matmul_ragged_blocks_per_shard(row_parallel):
+    """Under a (1, 2) mesh each shard's kernel gets a 640-wide local dim
+    (a ragged 512-lane tail) and reads its share of a stacked weight."""
+    from repro.dist import sharding as shd
+    from repro.launch.mesh import make_mesh
+    rng = np.random.default_rng(10)
+    k, n = (1280, 384) if row_parallel else (384, 1280)
+    x = _rand(rng, (8, k))
+    qt = quantize(_rand(rng, (2, k, n)), QUANT_CONFIGS[0])
+    qt = dataclasses.replace(qt, scale=qt.scale[1], layer=1)
+    y_ref = ops.axllm_matmul(x, qt, impl="ref")
+    mesh = make_mesh((1, 2), ("data", "model"),
+                     devices=jax.devices()[:2])
+    with shd.activate(mesh, shd.SERVE_RULES):
+        y_pal = jax.jit(lambda x, qt: ops.axllm_matmul(
+            x, qt, impl="pallas_interpret", row_parallel=row_parallel))(x, qt)
+    np.testing.assert_allclose(np.asarray(y_pal), np.asarray(y_ref),
+                               rtol=2e-5, atol=2e-4)
 
 
 def test_pick_blocks_per_group_alignment():

@@ -82,6 +82,34 @@ def test_budget_bounds_every_steps_prefill(params):
         sum(len(p) for p in MIXED)
 
 
+def test_budget_spent_by_a_first_token_finish_does_not_stall(params):
+    """A prompt that finishes on its first token spends most of the
+    step's budget; the queued prompt behind it cannot take a whole block
+    of what is left and nothing else is seated. The step ends there (it
+    did its prefill work) and the next one admits the queue, where the
+    engine used to raise "admission stalled"."""
+    prompts = [np.arange(10) + 3, np.arange(20) + 60]
+
+    def serve(eng, first=None):
+        for p, n in zip(prompts, (1, 3)):
+            eng.submit(p, max_new=n)
+        if first is not None:
+            first(eng)
+        while eng.step():
+            pass
+        return [r.tokens for r in sorted(eng.finished, key=lambda r: r.rid)]
+
+    def first_step(eng):
+        assert eng.step()                   # first prompt done, queue held
+        assert [r.max_new for r in eng.finished] == [1]
+        assert len(eng.queue) == 1
+
+    want = serve(ServeEngine(CFG, params, n_slots=2, max_len=64))
+    eng = _paged(params, prefill_budget=16)
+    assert serve(eng, first_step) == want
+    eng.pager.check_consistency()
+
+
 def test_prefill_budget_init_rejections(params):
     with pytest.raises(ValueError, match="paged"):
         ServeEngine(CFG, params, n_slots=2, max_len=64, prefill_budget=16)

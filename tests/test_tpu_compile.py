@@ -87,7 +87,10 @@ def test_paged_decode_attention_compiles(one_chip, kv):
 # shard of a column-parallel projection (N / 4 = 192 lanes); a 512-token
 # prefill chunk in one row block
 MATMULS = [(B, D, D), (B, D, HK * HD), (B, D, DFF), (B, DFF, D),
-           (128, DFF, D), (B, D, D // 4), (512, DFF, D)]
+           (128, DFF, D), (B, D, D // 4), (512, DFF, D),
+           # GLM-4-9B's FFN in a 128-token prefill chunk: ragged 256- and
+           # 512-lane blocks on d_ff 13696 = 107·128
+           (128, 4096, 13696), (128, 13696, 4096)]
 
 
 def _blocks(m, k, n):
@@ -139,10 +142,11 @@ def _defs(text):
         re.M)}
 
 
-def _weight_kernel_code_copies(one_chip, program):
-    """Compile ``program(params, cache)`` of the quantized repro-100m model
-    for one described v5e chip; return the number of axllm_matmul_pallas
-    calls and the s8 operands among them that are a copy of the codes.
+def _weight_kernel_code_copies(one_chip, program, cfg=CONFIG):
+    """Compile ``program(params, cache)`` of the quantized model ``cfg``
+    (repro-100m by default) for one described v5e chip; return the number
+    of axllm_matmul_pallas calls and the s8 operands among them that are a
+    copy of the codes.
 
     A parameter or tuple element is the codes as the program holds them,
     and a bitcast of one (the [1, K, N] view of a standalone weight) is no
@@ -153,8 +157,8 @@ def _weight_kernel_code_copies(one_chip, program):
     from repro.models import transformer as T
 
     params = jax.eval_shape(lambda: deploy_quantize(
-        T.init_params(jax.random.PRNGKey(0), CONFIG), QuantConfig(bits=8)))
-    cache = jax.eval_shape(lambda: T.init_cache(CONFIG, B, S))
+        T.init_params(jax.random.PRNGKey(0), cfg), QuantConfig(bits=8)))
+    cache = jax.eval_shape(lambda: T.init_cache(cfg, B, S))
     put = lambda t: jax.tree_util.tree_map(             # noqa: E731
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         t)
@@ -162,7 +166,7 @@ def _weight_kernel_code_copies(one_chip, program):
     defs = _defs(text)
     calls = re.findall(r"^\s*(?:ROOT )?%axllm_matmul_pallas[.\d]* = \S+ "
                        r"custom-call\(([^)]*)\)", text, re.M)
-    stack = f"s8[{CONFIG.n_layers},"
+    stack = f"s8[{cfg.n_layers},"
     copies = []
     for operands in calls:
         for name in re.findall(r"%([\w.\-]+)", operands):
@@ -198,3 +202,59 @@ def test_prefill_reads_codes_in_place(one_chip):
         T.prefill(p, tokens, CONFIG, c, impl="pallas")))
     assert n >= 7
     assert not copies, copies
+
+
+def _decode_step_blocks(one_chip, cfg, monkeypatch):
+    """Compile ``cfg``'s quantized decode step over B slots for one
+    described chip; return the code copies feeding a weight kernel (as
+    :func:`_weight_kernel_code_copies`) and each weight-kernel call's
+    (M, K, N, codes rank, blocks) as traced."""
+    from repro.kernels import axllm_matmul as amm
+    from repro.models import transformer as T
+
+    calls, kernel = [], amm.axllm_matmul_pallas
+
+    def spy(x, codes, scale, *args, blocks, **kw):
+        calls.append((*x.shape, scale.shape[-1], codes.ndim, blocks))
+        return kernel(x, codes, scale, *args, blocks=blocks, **kw)
+
+    monkeypatch.setattr(amm, "axllm_matmul_pallas", spy)
+    token = jnp.zeros((B,), jnp.int32)
+    n, copies = _weight_kernel_code_copies(one_chip, lambda p, c: (
+        T.decode_step(p, token, cfg, c, impl="pallas")), cfg)
+    assert n >= 8 and calls
+    return copies, calls
+
+
+def test_glm4_decode_step_takes_full_lane_tiles(one_chip, monkeypatch):
+    """GLM-4-9B at its published widths (two of its 40 layers: the layer
+    scan compiles one body whatever the depth): the decode step compiles
+    for a v5e, and its FFN kernels, whose d_ff 13696 = 107·128 has no
+    factor of 256, take 512-lane tiles with a ragged last block on the
+    whole [L, K, N] stack of codes, where they fell to 128 lanes."""
+    import dataclasses
+    from repro.configs.glm4_9b import CONFIG as GLM
+
+    cfg = dataclasses.replace(GLM, n_layers=2)
+    copies, calls = _decode_step_blocks(one_chip, cfg, monkeypatch)
+    assert not copies, copies
+    ffn = [c for c in calls if cfg.d_ff in c[1:3]]
+    assert len(ffn) == 3                        # gate, up, down
+    for m, k, n, rank, (bm, bk, bn) in ffn:
+        assert rank == 3 and bk == 512 and bn == 512, (m, k, n, bk, bn)
+
+
+def test_granite_decode_step_keeps_its_blocks(one_chip, monkeypatch):
+    """Granite-3.0-8B's widths divide by 512: its decode step's weight
+    kernels take exactly the blocks of the table without ragged tiles,
+    the blocks its chip numbers were measured with."""
+    import dataclasses
+    from repro.configs.granite_3_8b import CONFIG as GRANITE
+
+    cfg = dataclasses.replace(GRANITE, n_layers=2)
+    copies, calls = _decode_step_blocks(one_chip, cfg, monkeypatch)
+    assert not copies, copies
+    assert len(calls) == 8                      # 7 per layer + the head
+    for m, k, n, _, blocks in calls:
+        assert blocks == ops.pick_blocks(m, k, n, ragged=False)[:3]
+        assert blocks[1] == 512 and k % 512 == 0
