@@ -61,6 +61,24 @@ def _row_scales(scale_tile, h: int, rep: int):
         preferred_element_type=jnp.float32)
 
 
+def fold_scores(s, valid, v, v_rows, m_ref, l_ref, acc_ref):
+    """Fold one tile's scores into the online-softmax state of every query
+    head: s/valid [H, n] (masked keys already at NEG_INF), v [n, w] f32,
+    v_rows None or [H, n] per-key value scales; m_ref/l_ref [H, 128]
+    running max / denominator; acc_ref [H, w]."""
+    m_prev = m_ref[:, :1]                                  # [H, 1]
+    l_prev = l_ref[:, :1]
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new) * valid.astype(jnp.float32)
+    corr = jnp.exp(m_prev - m_new)
+    l_new = l_prev * corr + p.sum(axis=-1, keepdims=True)
+    pv = p if v_rows is None else p * v_rows
+    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot(
+        pv, v, precision=_HIGHEST, preferred_element_type=jnp.float32)
+    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+
 def attend_tile(q_ref, k_ref, v_ref, ks_ref, vs_ref, m_ref, l_ref, acc_ref,
                 *, length, kpos0, scale: float, rep: int):
     """Fold one KV tile into the online-softmax state of every query head.
@@ -81,18 +99,9 @@ def attend_tile(q_ref, k_ref, v_ref, ks_ref, vs_ref, m_ref, l_ref, acc_ref,
     kpos = kpos0 + jax.lax.broadcasted_iota(jnp.int32, (h, bs), 1)
     valid = kpos < length
     s = jnp.where(valid, s, NEG_INF)
-
-    m_prev = m_ref[:, :1]                                  # [H, 1]
-    l_prev = l_ref[:, :1]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new) * valid.astype(jnp.float32)
-    corr = jnp.exp(m_prev - m_new)
-    l_new = l_prev * corr + p.sum(axis=-1, keepdims=True)
-    pv = p if vs_ref is None else p * _row_scales(vs_ref[0], h, rep)
-    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot(
-        pv, v, precision=_HIGHEST, preferred_element_type=jnp.float32)
-    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+    fold_scores(s, valid, v,
+                None if vs_ref is None else _row_scales(vs_ref[0], h, rep),
+                m_ref, l_ref, acc_ref)
 
 
 def init_state(m_ref, l_ref, acc_ref):

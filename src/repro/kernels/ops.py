@@ -394,7 +394,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
 
 
 def decode_attention(q, k_cache, v_cache, length, *, k_scale=None,
-                     v_scale=None, block_tables=None,
+                     v_scale=None, block_tables=None, layer=None,
                      impl: str = "auto") -> jax.Array:
     """q: [B, H, d]; caches [B, S, Hk, d] (int8 if scales given); length [B].
 
@@ -403,11 +403,18 @@ def decode_attention(q, k_cache, v_cache, length, *, k_scale=None,
     concatenation of its table's blocks, and the paged flash-decode kernel
     gathers KV tiles through the table (scalar-prefetch index map) so
     prefix-shared blocks stream from HBM once per referencing row without
-    ever being materialized contiguously.
+    ever being materialized contiguously. With ``layer`` as well (an int32
+    scalar) the pools are the stack [L, NB, bs, Hk, d] of every layer's
+    pool, and the kernel reads layer ``layer``'s blocks in place; under a
+    mesh the leading L axis stays whole on every shard.
     """
     impl = _base_impl(impl)
     if not _use_pallas(impl):
         if block_tables is not None:
+            if layer is not None:
+                k_cache, v_cache = k_cache[layer], v_cache[layer]
+                if k_scale is not None:
+                    k_scale, v_scale = k_scale[layer], v_scale[layer]
             return _ref.paged_decode_attention_ref(
                 q, k_cache, v_cache, block_tables, length,
                 k_scale=k_scale, v_scale=v_scale)
@@ -419,16 +426,23 @@ def decode_attention(q, k_cache, v_cache, length, *, k_scale=None,
     interpret = _interpret(impl)
     if block_tables is not None:
         from repro.kernels import paged_decode_attention as _pda
+        extra, extra_names = (block_tables, length), (("batch", None),
+                                                      ("batch",))
+        if layer is not None:
+            extra += (jnp.asarray(layer, jnp.int32),)
+            extra_names += ((),)
+
+        n_sc = len(kv) - 2
 
         def paged(q, k, v, *rest):
-            *sc, bt, ln = rest
+            sc, (bt, ln, *ly) = rest[:n_sc], rest[n_sc:]
             return _pda.paged_decode_attention_pallas(
-                q, k, v, bt, ln, k_scale=sc[0] if sc else None,
+                q, k, v, bt, ln, *ly, k_scale=sc[0] if sc else None,
                 v_scale=sc[1] if sc else None, interpret=interpret)
 
-        return _attention_call(paged, q, kv, (block_tables, length), q_names,
-                               (None, None, "kv_heads", None),
-                               (("batch", None), ("batch",)))
+        stack = (None,) * (k_cache.ndim - 2)
+        return _attention_call(paged, q, kv, extra, q_names,
+                               stack + ("kv_heads", None), extra_names)
     from repro.kernels import decode_attention as _da
 
     def dense(q, k, v, *rest):

@@ -295,56 +295,49 @@ def _seq_shard_ctx(cfg, batch: int, cache_len: int):
     return mesh, seq_axes, batch_axes
 
 
-def attention_decode_paged(p, x, cfg, layer_pool, pos, block_tables, *,
+def attention_decode_paged(p, x, cfg, pool, layer, pos, block_tables, *,
                            impl: str = "auto", adapters=None,
                            adapter_idx=None, lora_scaling: float = 1.0):
     """One-token decode through a block-paged KV pool.
 
-    x: [B, 1, d]; pos: [B] current positions; layer_pool: this layer's
-    pool slice ``{"k"/"v": [NB, bs, Hk, hd], ...}``; block_tables:
-    [B, MB] int32. The new KV entry is written at
-    ``(table[pos // bs], pos % bs)`` — the scheduler guarantees the
-    written block is uniquely owned (copy-on-write resolves sharing
+    x: [B, 1, d]; pos: [B] current positions; pool: every layer's pool
+    ``{"k"/"v": [L, NB, bs, Hk, hd], ...}``, of which this is layer
+    ``layer`` (an int32 scalar in the layer scan, concrete unrolled);
+    block_tables: [B, MB] int32. The new KV entry is written in place at
+    ``(layer, table[pos // bs], pos % bs)`` — the scheduler guarantees
+    the written block is uniquely owned (copy-on-write resolves sharing
     before the chunk dispatches), and rows whose position ran past their
     table (stopped slots riding through a scan) are routed to trash
-    block 0. Attention reads gather through the table in the paged
-    flash-decode kernel. ``adapters``/``adapter_idx`` as in
+    block 0. Attention reads the layer's blocks through the table in the
+    paged flash-decode kernel, straight out of the stack. Returns (out,
+    the updated pool). ``adapters``/``adapter_idx`` as in
     :func:`attention_prefill`.
     """
     b = x.shape[0]
-    h, hk, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
-    bs = layer_pool["k"].shape[1]
+    h, hd = cfg.n_heads, cfg.resolved_head_dim
+    bs = pool["k"].shape[2]
     mb = block_tables.shape[1]
     q, k, v = _project_qkv(p, x, cfg, impl, adapters, adapter_idx,
                            lora_scaling)             # [B, 1, ...]
     q = L.rope(q, pos[:, None], cfg.rope_theta)
     k = L.rope(k, pos[:, None], cfg.rope_theta)
 
-    bidx_row = jnp.arange(b)
     blk = pos // bs
     in_range = blk < mb
     bid = jnp.where(in_range,
-                    block_tables[bidx_row, jnp.clip(blk, 0, mb - 1)], 0)
+                    block_tables[jnp.arange(b), jnp.clip(blk, 0, mb - 1)], 0)
     off = jnp.where(in_range, pos % bs, 0)
-    pool = dict(layer_pool)
     if cfg.quant_kv:
-        kq, ksc = _quantize_kv(k)
-        vq, vsc = _quantize_kv(v)
-        pool["k"] = layer_pool["k"].at[bid, off].set(kq[:, 0])
-        pool["v"] = layer_pool["v"].at[bid, off].set(vq[:, 0])
-        pool["k_scale"] = layer_pool["k_scale"].at[bid, off].set(ksc[:, 0])
-        pool["v_scale"] = layer_pool["v_scale"].at[bid, off].set(vsc[:, 0])
-        out = ops.decode_attention(
-            q[:, 0], pool["k"], pool["v"], pos + 1,
-            k_scale=pool["k_scale"], v_scale=pool["v_scale"],
-            block_tables=block_tables, impl=impl)
+        (kq, ksc), (vq, vsc) = _quantize_kv(k), _quantize_kv(v)
+        new = {"k": kq, "v": vq, "k_scale": ksc, "v_scale": vsc}
     else:
-        pool["k"] = layer_pool["k"].at[bid, off].set(
-            k[:, 0].astype(layer_pool["k"].dtype))
-        pool["v"] = layer_pool["v"].at[bid, off].set(
-            v[:, 0].astype(layer_pool["v"].dtype))
-        out = ops.decode_attention(q[:, 0], pool["k"], pool["v"], pos + 1,
-                                   block_tables=block_tables, impl=impl)
+        new = {"k": k, "v": v}
+    pool = {name: a.at[layer, bid, off].set(new[name][:, 0].astype(a.dtype))
+            for name, a in pool.items()}
+    out = ops.decode_attention(
+        q[:, 0], pool["k"], pool["v"], pos + 1, k_scale=pool.get("k_scale"),
+        v_scale=pool.get("v_scale"), block_tables=block_tables, layer=layer,
+        impl=impl)
     out = out.reshape(b, 1, h * hd)
     return _wo_project(p, out, impl, adapters, adapter_idx,
                        lora_scaling), pool

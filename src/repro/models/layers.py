@@ -41,6 +41,12 @@ def maybe_scan(body, carry, xs, use_scan: bool = True):
     return carry, ys
 
 
+def layer_indices(n: int, use_scan: bool = True):
+    """Each layer's index as :func:`maybe_scan` hands it to a step: an
+    int32 scalar in the scan, a concrete index unrolled."""
+    return jnp.arange(n, dtype=jnp.int32) if use_scan else np.arange(n)
+
+
 def scan_layer_params(body, carry, layers, xs, use_scan: bool = True):
     """:func:`maybe_scan` of ``body(carry, (lp, *xs_i))`` over the stacked
     layer params ``layers`` and the per-layer tuple ``xs``, with every
@@ -57,8 +63,8 @@ def scan_layer_params(body, carry, layers, xs, use_scan: bool = True):
     codes = [a.codes if isinstance(a, QTensor) else None for a in leaves]
     rest = [dataclasses.replace(a, codes=None) if isinstance(a, QTensor)
             else a for a in leaves]
-    n = jax.tree_util.tree_leaves(layers)[0].shape[0]
-    index = jnp.arange(n, dtype=jnp.int32) if use_scan else np.arange(n)
+    index = layer_indices(jax.tree_util.tree_leaves(layers)[0].shape[0],
+                          use_scan)
 
     def step(c, inp):
         i, rest_i, *xs_i = inp

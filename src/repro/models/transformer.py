@@ -227,43 +227,53 @@ def decode_step(params, token, cfg, cache, impl: str = "auto",
     of base and N distinct adapters decodes in one dispatch.
 
     A cache carrying ``block_tables`` (built by :func:`init_paged_cache`)
-    routes every layer through the block-paged decode path: KV writes land
-    at ``(table[pos // bs], pos % bs)`` in the shared pool and attention
-    gathers through the table (``ops.decode_attention(block_tables=)``).
+    routes every layer through the block-paged decode path: the stacked
+    pool rides through the layer scan in the carry, each layer's KV write
+    lands in place at ``(layer, table[pos // bs], pos % bs)``, and
+    attention reads the layer's blocks straight out of the stack through
+    the table (``ops.decode_attention(block_tables=, layer=)``), so no
+    layer's pool is sliced out, relaid or written back.
     """
     pos = cache["pos"]
     block_tables = cache.get("block_tables")
+    paged = block_tables is not None
     x = L.embed_fwd(params["embed"], token[:, None]).astype(_param_dtype(cfg))
 
     def body(carry, inp):
-        if adapters is None:
-            (lp, lc), ad = inp, None
-        else:
-            lp, lc, ad = inp
-        h = L.norm_fwd(lp["ln1"], carry, cfg.norm_eps)
-        if block_tables is not None:
-            att, new_lc = A.attention_decode_paged(
-                lp["attn"], h, cfg, lc, pos, block_tables, impl=impl,
+        # paged, the pool rides in the carry and lc is this layer's index
+        # into it; dense, lc is this layer's cache slice
+        x, pool = carry
+        lp, lc, *ad = inp
+        ad = ad[0] if ad else None
+        h = L.norm_fwd(lp["ln1"], x, cfg.norm_eps)
+        if paged:
+            att, pool = A.attention_decode_paged(
+                lp["attn"], h, cfg, pool, lc, pos, block_tables, impl=impl,
                 adapters=ad, adapter_idx=adapter_idx,
                 lora_scaling=lora_scaling)
         else:
-            att, new_lc = A.attention_decode(
+            att, lc = A.attention_decode(
                 lp["attn"], h, cfg, lc, pos, impl=impl, adapters=ad,
                 adapter_idx=adapter_idx, lora_scaling=lora_scaling)
-        x1 = carry + att
+        x1 = x + att
         h2 = L.norm_fwd(lp["ln2"], x1, cfg.norm_eps)
         x2 = x1 + _ffn_fwd(lp["ffn"], h2, cfg, impl)
-        return x2, new_lc
+        return (x2, pool), None if paged else lc
 
-    xs = (_cache_xs(cache),)
+    if paged:
+        pool = _cache_xs(cache)
+        xs = (L.layer_indices(cache["k"].shape[0], cfg.scan_layers),)
+    else:
+        pool, xs = None, (_cache_xs(cache),)
     if adapters is not None:
         xs = xs + (adapters,)
-    x, new_kv = L.scan_layer_params(body, x, params["layers"], xs,
-                                    cfg.scan_layers)
+    (x, pool), ys = L.scan_layer_params(body, (x, pool), params["layers"],
+                                        xs, cfg.scan_layers)
+    new_kv = pool if paged else ys
     x = L.norm_fwd(params["final_norm"], x, cfg.norm_eps)
     logits = L.head_fwd(params["embed"], x, cfg, impl=impl)[:, 0]
     new_cache = dict(new_kv)
     new_cache["pos"] = pos + 1
-    if block_tables is not None:
+    if paged:
         new_cache["block_tables"] = block_tables
     return logits, new_cache

@@ -3,6 +3,8 @@ identical to n sequential api.decode calls — fp and deploy-quantized,
 across all four families, plus stop-mask semantics and the Pallas
 interpret-mode path."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -97,6 +99,54 @@ def test_scan_decode_interpret_mode():
     cache2 = jax.tree_util.tree_map(jnp.array, cache)
     seq = _sequential(cfg, api, params, last, cache, 3)
     got = _chunked(cfg, api, params, last, cache2, 3)
+    np.testing.assert_array_equal(got, seq)
+
+
+def _paged_from_dense(cache, bs: int, seed: int = 0):
+    """The dense cache's KV scattered into a block pool through shuffled
+    per-row block tables (block 0, the trash block, left out), every
+    layer at once: [L, B, S, Hk, d] -> pool [L, 1 + B·S/bs, bs, Hk, d]."""
+    nl, b, s = cache["k"].shape[:3]
+    mb = s // bs
+    order = 1 + np.random.default_rng(seed).permutation(b * mb)
+    tables = order.reshape(b, mb)
+    paged = {"pos": cache["pos"],
+             "block_tables": jnp.asarray(tables, jnp.int32)}
+    for name, a in cache.items():
+        if name == "pos":
+            continue
+        blocks = a.reshape(nl, b * mb, bs, *a.shape[3:])
+        pool = jnp.zeros((nl, 1 + b * mb, bs, *a.shape[3:]), a.dtype)
+        paged[name] = pool.at[:, tables.reshape(-1)].set(blocks)
+    return paged
+
+
+PAGED = {"fp": (DENSE, False, "auto"),
+         "axllm-int8": (DENSE, True, "auto"),
+         "int8-kv-3-layers": (dataclasses.replace(DENSE, n_layers=3,
+                                                  quant_kv=True),
+                              False, "auto"),
+         "interpret": (DENSE, True, "pallas_interpret")}
+
+
+@pytest.mark.parametrize("mode", sorted(PAGED))
+def test_paged_scan_decode_matches_dense(mode):
+    """decode_steps over a block-paged cache, whose pool rides through
+    the layer scan and is written and read in place at each layer's
+    index, emits the same tokens as sequential decode over the dense
+    cache (its own code path): a token written into another layer's
+    pool, or read from one, changes the stream."""
+    cfg, quantized, impl = PAGED[mode]
+    api = get_model(cfg, impl=impl)
+    params = api.init(jax.random.PRNGKey(0))
+    if quantized:
+        params = deploy_quantize(params, QuantConfig(
+            bits=8, mode="affine", granularity="per_channel"))
+    last, cache = _prefill(cfg, params, api)
+    paged = _paged_from_dense(cache, bs=8)
+    n = 3 if impl == "pallas_interpret" else N
+    seq = _sequential(cfg, api, params, last, cache, n)
+    got = _chunked(cfg, api, params, last, paged, n)
     np.testing.assert_array_equal(got, seq)
 
 

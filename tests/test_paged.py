@@ -91,6 +91,47 @@ def test_paged_decode_kernel_int8_kv():
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@pytest.mark.parametrize("h,hk", [(8, 2), (16, 1)], ids=["gqa4", "gqa16"])
+def test_stacked_pool_kernel_vs_oracle(kv, h, hk):
+    """The kernel reads one layer of an [L, NB, bs, Hk, d] stack in place
+    (the layer a prefetched index): it matches the oracle on that layer's
+    slice, at a non-zero layer whose neighbours hold other values, over
+    ragged lengths (empty, mid-block, full) and rows whose tables run
+    into the trash block, which holds large finite garbage."""
+    nl, layer, b, bs, mb, d = 3, 1, 4, 16, 4, 32
+    nb = b * mb + 1
+    rng = np.random.default_rng(15)
+    q = _rand(rng, (b, h, d))
+    k, v = _rand(rng, (nl, nb, bs, hk, d)), _rand(rng, (nl, nb, bs, hk, d))
+    k, v = k.at[:, 0].set(1e4), v.at[:, 0].set(-1e4)
+    length = jnp.asarray([0, bs + 3, 2 * bs, mb * bs], jnp.int32)
+    tables = 1 + rng.permutation(nb - 1).reshape(b, mb)
+    # past its length a row's table names the trash block, as it does
+    # for a stopped slot or a block not yet allocated
+    past = np.arange(mb)[None, :] * bs >= np.asarray(length)[:, None]
+    bt = jnp.asarray(np.where(past, 0, tables), jnp.int32)
+    kw = {}
+    if kv == "int8":
+        (k, ks), (v, vs) = _kv_quant(k), _kv_quant(v)
+        kw = dict(k_scale=ks, v_scale=vs)
+    else:
+        k, v = k.astype(jnp.bfloat16), v.astype(jnp.bfloat16)
+    want = ref.paged_decode_attention_ref(
+        q, k[layer], v[layer], bt, length,
+        **{n: a[layer] for n, a in kw.items()})
+    for impl in ("pallas_interpret", "ref"):
+        got = ops.decode_attention(q, k, v, length, block_tables=bt,
+                                   layer=jnp.int32(layer), impl=impl, **kw)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5)
+    assert np.allclose(np.asarray(want[0]), 0.0)
+    other = ref.paged_decode_attention_ref(
+        q, k[layer + 1], v[layer + 1], bt, length,
+        **{n: a[layer + 1] for n, a in kw.items()})
+    assert not np.allclose(np.asarray(other), np.asarray(want), atol=1e-2)
+
+
 def test_prefix_attention_matches_causal_oracle():
     """Suffix-only prefill attention == full causal attention restricted to
     the suffix rows, per row of a ragged (padded) prefix."""

@@ -11,6 +11,7 @@ one process at a time may load the TPU library, and every test worker
 imports this file.
 """
 
+import math
 import re
 
 import jax
@@ -258,3 +259,97 @@ def test_granite_decode_step_keeps_its_blocks(one_chip, monkeypatch):
     for m, k, n, _, blocks in calls:
         assert blocks == ops.pick_blocks(m, k, n, ragged=False)[:3]
         assert blocks[1] == 512 and k % 512 == 0
+
+
+_ITEM_BYTES = {"s8": 1, "u8": 1, "pred": 1, "bf16": 2, "f16": 2}
+
+
+def _nbytes(ty):
+    """Bytes of an HLO array type such as bf16[2,148,128,8,128]{...}; 0
+    for a tuple or token."""
+    m = re.match(r"(\w+)\[([\d,]*)\]", ty)
+    if not m:
+        return 0
+    dims = [int(d) for d in m[2].split(",") if d]
+    return math.prod(dims) * _ITEM_BYTES.get(m[1], 4)
+
+
+def _fused_root(text, fusion):
+    """Opcode of the root of the computation that fusion ``fusion``
+    calls."""
+    called = re.search(rf"%{re.escape(fusion)} = .*? calls=%([\w.\-]+)",
+                       text)[1]
+    body = re.search(rf"^%{re.escape(called)} .*?^}}", text, re.M | re.S)[0]
+    return re.search(r"ROOT %[\w.\-]+ = \S+ ([\w\-]+)\(", body)[1]
+
+
+# (config, slots, pool blocks of 128 tokens, table width): the cells' own
+_POOLS = {"granite-3-8b": (4, 148, 32), "glm4-9b": (3, 450, 149)}
+
+
+@pytest.mark.parametrize("arch", sorted(_POOLS))
+def test_paged_decode_chunk_keeps_pool_in_place(one_chip, arch):
+    """The engine's paged decode chunk (``decode_steps`` over
+    ``T.decode_step``), at published widths and two layers, updates and
+    reads the stacked KV pool in place: no copy, slice, write-back,
+    relayout or fusion makes a value the size of one layer's pool, but
+    the one-token scatter that updates the stack in place; every KV
+    operand of the paged kernel is the whole stack; and the chunk needs
+    less scratch memory than one layer's pool."""
+    import dataclasses
+    from repro.configs import get_config
+    from repro.core.axllm_linear import deploy_quantize
+    from repro.core.quantization import QuantConfig
+    from repro.models import transformer as T
+    from repro.serve.decode import decode_steps
+
+    cfg = dataclasses.replace(get_config(arch), n_layers=2)
+    b, nb, mb = _POOLS[arch]
+    params = jax.eval_shape(lambda: deploy_quantize(
+        T.init_params(jax.random.PRNGKey(0), cfg), QuantConfig(bits=8)))
+    cache = jax.eval_shape(lambda: T.init_paged_cache(
+        cfg, b, nb, 128, mb, jnp.bfloat16))
+    stack = cache["k"]
+    layer_bytes = math.prod(stack.shape[1:]) * stack.dtype.itemsize
+
+    def chunk(params, last, cache, rng, stop, gen, max_new):
+        return decode_steps(
+            lambda p, t, c: T.decode_step(p, t, cfg, c, impl="pallas"),
+            params, last, cache, rng, stop, gen, max_new, n=8,
+            vocab_size=cfg.vocab_size, max_len=mb * 128)
+
+    put = lambda t: jax.tree_util.tree_map(             # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    rows = jax.ShapeDtypeStruct((b,), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(chunk, donate_argnums=(2,)).lower(
+        put(params), rows, put(cache), put(jax.random.PRNGKey(0)),
+        jax.ShapeDtypeStruct((b,), bool, sharding=one_chip), rows,
+        rows).compile()
+    text = compiled.as_text()
+    defs = _defs(text)
+    moved = [(name, ty, op) for name, (ty, op, _) in defs.items()
+             if _nbytes(ty) >= layer_bytes
+             and op.startswith(("copy", "dynamic-slice",
+                                "dynamic-update-slice", "reshape", "fusion",
+                                "slice"))
+             and not (op == "fusion" and _fused_root(text, name) == "scatter")]
+    assert not moved, moved
+
+    whole = f"bf16[{','.join(map(str, stack.shape))}]"
+    calls = re.findall(r"^\s*(?:ROOT )?%paged_decode_attention_pallas[.\d]* = "
+                       r"\S+ custom-call\(([^)]*)\)", text, re.M)
+    assert calls
+    for operands in calls:
+        kv = []
+        for name in re.findall(r"%([\w.\-]+)", operands):
+            ty, op, args = defs[name]
+            while op == "bitcast":
+                ty, op, args = defs[re.search(r"%([\w.\-]+)", args)[1]]
+            if _nbytes(ty) >= layer_bytes:
+                kv.append((name, ty, op))
+        assert len(kv) == 2, kv                      # keys and values
+        for name, ty, op in kv:
+            assert ty.startswith(whole) or op in (
+                "parameter", "get-tuple-element"), (name, ty, op)
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
