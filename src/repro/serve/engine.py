@@ -123,12 +123,23 @@ Scheduler contract
   (the scan writes KV unconditionally for every row). Mid-prefill
   preemption publishes the consumed prefix and re-admits through the
   normal prefill path, where the radix match re-hits it —
-  token-identical, no swap state. Budgeted waves draw their shape from
-  a small fixed lattice — pow2 width buckets up to `n_slots`, pow2
-  length buckets capped by the budget — so compile count is bounded and
-  independent of arrival pattern without padding single-request chunks
-  to the full slot set. Greedy output is bit-identical to unbudgeted
-  serving.
+  token-identical, no swap state. First chunks and continuations are
+  two plans feeding ONE paged wave (`_paged_wave`): admission plans a
+  radix match, a budget cut and `pager.admit`; continuation plans a
+  budget cut and `pager.extend`, preempting victims while the pool
+  cannot cover the wave. The wave prefills each row's chunk against the
+  blocks its table starts with (a prefix hit, or the slot's own
+  consumed blocks), rolls a faulted dispatch back row by row, and
+  harvests. Continuations dispatch first, so a step runs at most two
+  paged waves. Every wave draws its shape from one lattice — pow2 width
+  up to `n_slots`, pow2 chunk length capped by the budget, pow2 prefix
+  blocks — so compile count is bounded and independent of arrival
+  pattern without padding single-request chunks to the full slot set.
+  Greedy output is bit-identical to unbudgeted serving.
+- **Settling a slot.** Wherever tokens are appended to a running slot
+  (a prefill wave's first token, a decode chunk, a speculative round),
+  one path (`_settle`) stamps `t_last`, streams them, and on a stop or a
+  cancel publishes the slot's KV, releases it and finishes the request.
 - **Streaming + cancellation.** `submit(on_token=...)` fires the
   callback per token at chunk harvest (prefill first-token, decode
   chunk, speculative round); `stream()` wraps submit + step into a
@@ -182,7 +193,8 @@ Scheduler contract
   speculation — drafted/accepted token counts with `acceptance_rate`
   and `accepted_tokens_per_step` (emitted per slot-round, > 1 means
   drafting beats one-token-per-step);
-  `stats.as_dict()` feeds `benchmarks/serve_bench.py`.
+  `stats.as_dict()` feeds `benchmarks/serve_bench.py` and the chip
+  benchmark's `bench/run.py`.
 
 `generate()` returns token lists for all submitted prompts; requests
 still in flight when `max_steps` runs out come back with their partial
@@ -196,7 +208,7 @@ import dataclasses
 import functools
 import math
 import time
-from typing import List, Optional
+from typing import List, NamedTuple, Optional
 
 import numpy as np
 
@@ -424,6 +436,17 @@ def _pow2_bucket(n: int, lo: int, hi: int) -> int:
     while b < n:
         b *= 2
     return min(b, hi)
+
+
+class _Row(NamedTuple):
+    """One row of a paged prefill wave: ``req`` in ``slot`` prefills
+    ``seq[cursor:cursor + take]`` against the ``cursor // block`` blocks
+    its slot's table starts with."""
+    slot: int
+    req: Request
+    seq: np.ndarray
+    cursor: int
+    take: int
 
 
 class ServeEngine:
@@ -931,8 +954,7 @@ class ServeEngine:
                     jnp.asarray(sw.tail[name], self.cache[name].dtype))
         r._swap = None
         self.slots[slot] = r
-        self.adapter_slots[slot] = (self.registry.index_of(r.adapter)
-                                    if r.adapter is not None else -1)
+        self.adapter_slots[slot] = self._adapter_row(r)
         self.stats.restored += 1
         self.stats.fast_restores += 1
         return True
@@ -999,6 +1021,23 @@ class ServeEngine:
             self.stats.blocks_in_use = self.pager.blocks_in_use
         self.slots[i] = None
         self.adapter_slots[i] = -1
+
+    def _settle(self, i: int, now: float) -> bool:
+        """Settle slot ``i`` after tokens were appended to its request:
+        stamp ``t_last``, stream them, and when a stop condition or a
+        cancel ends the request, tear the slot down (publish, release,
+        clear) and finish it. Returns True when the slot was freed."""
+        r = self.slots[i]
+        r.t_last = now
+        want_cancel = self._emit(r, now)
+        reason = self._stop_reason(r)
+        if reason is None and want_cancel:
+            reason = "cancelled"
+        if reason is None:
+            return False
+        self._teardown_slot(i)
+        self._finish(r, reason)
+        return True
 
     # -- streaming (per-token emission + cancellation) ---------------------------
     def _emit(self, r: Request, now: float) -> bool:
@@ -1119,7 +1158,7 @@ class ServeEngine:
             try:
                 for gi, group in enumerate(groups):
                     if self.paged:
-                        self._prefill_group_paged(group, free)
+                        self._admit_paged(group, free)
                     else:
                         self._prefill_group(group, free)
                 with TraceAnnotation("engine.prefill_sync"):
@@ -1137,28 +1176,40 @@ class ServeEngine:
             finally:
                 self.stats.prefill_wall_s += time.perf_counter() - t0
 
+    def _adapter_row(self, r: Request) -> int:
+        """Row of ``r``'s adapter in ``registry.stacked`` (-1: base)."""
+        return -1 if r.adapter is None else self.registry.index_of(r.adapter)
+
+    def _lora_args(self, aidx) -> tuple:
+        """Trailing ``(stacked, aidx)`` arguments of every jitted program:
+        empty without a registry, so the program is the base model's."""
+        if self.registry is None:
+            return ()
+        return self.registry.stacked, jnp.asarray(aidx)
+
+    def _with_lora(self, fn, stacked, aidx):
+        """``api.prefill`` / ``api.decode`` with the gathered LoRA delta
+        bound when a program was given adapter arguments (traced: the
+        stacked A/B pytree and the rows' adapter indices are jit
+        arguments, so hot add/evict never invalidates a compile)."""
+        if stacked is None:
+            return fn
+        return functools.partial(fn, adapters=stacked, adapter_idx=aidx,
+                                 lora_scaling=self.registry.scaling)
+
     def _get_prefill(self, wave_bucket: int, padded_len: int):
-        """Jitted prefill for one (wave, padded_len) bucket. With an
-        adapter registry the callable additionally takes the stacked A/B
-        pytree and the wave's [wb] adapter-index row as jit arguments, so
-        hot add/evict never invalidates the compile cache."""
+        """Jitted dense prefill for one (wave, padded_len) bucket."""
         key = (wave_bucket, padded_len)
         if key not in self._prefill_cache:
             api, max_len = self.api, self.max_len
-            lora = self.registry is not None
-            scaling = self.registry.scaling if lora else None
             ragged = api.ragged_prefill
 
             def prefill(params, toks, lengths, stacked=None, aidx=None):
                 cache = api.init_cache(toks.shape[0], max_len)
-                kw = {}
-                if ragged:
-                    kw["lengths"] = lengths
-                if lora:
-                    kw.update(adapters=stacked, adapter_idx=aidx,
-                              lora_scaling=scaling)
-                logits, wave_cache = api.prefill(params, {"tokens": toks},
-                                                 cache, **kw)
+                kw = {"lengths": lengths} if ragged else {}
+                logits, wave_cache = self._with_lora(api.prefill, stacked,
+                                                     aidx)(
+                    params, {"tokens": toks}, cache, **kw)
                 return logits, self._constrain_wave(wave_cache,
                                                     toks.shape[0])
 
@@ -1167,6 +1218,8 @@ class ServeEngine:
         return self._prefill_cache[key]
 
     def _prefill_group(self, group: List[Request], free: List[int]):
+        """Dense admission wave: prefill whole sequences, then write the
+        rows that stay seated into their slots' cache rows."""
         t_seat = self._now()
         w = len(group)
         wb = _pow2_bucket(w, 1, self.n_slots)
@@ -1182,8 +1235,7 @@ class ServeEngine:
         for i, (r, seq) in enumerate(zip(group, seqs)):
             toks[i, : len(seq)] = seq
             lengths[i] = len(seq)
-            if r.adapter is not None:
-                aidx[i] = self.registry.index_of(r.adapter)
+            aidx[i] = self._adapter_row(r)
         fn = self._get_prefill(wb, pl)
         try:
             with TraceAnnotation("engine.prefill", rows=w, tokens=sum(lens),
@@ -1191,14 +1243,9 @@ class ServeEngine:
                                  kind="first"):
                 if self.fault_hook is not None:
                     self.fault_hook("prefill")
-                if self.registry is not None:
-                    logits, wave_cache = fn(self.params, jnp.asarray(toks),
-                                            jnp.asarray(lengths),
-                                            self.registry.stacked,
-                                            jnp.asarray(aidx))
-                else:
-                    logits, wave_cache = fn(self.params, jnp.asarray(toks),
-                                            jnp.asarray(lengths))
+                logits, wave_cache = fn(self.params, jnp.asarray(toks),
+                                        jnp.asarray(lengths),
+                                        *self._lora_args(aidx))
         except Exception:
             # nothing was mutated yet (no slot/cache writes): requeue the
             # whole group so no request — or its adapter pin — is lost
@@ -1212,44 +1259,23 @@ class ServeEngine:
             for i, r in enumerate(group):
                 r.tokens.append(int(first[i]))
                 self._count_admission(r, t_seat)
-                r.t_last = now
                 r._swap = None
                 self.stats.prefill_tokens += int(lengths[i])
-                want_cancel = self._emit(r, now)
-                reason = self._stop_reason(r)
-                if reason is None and want_cancel:
-                    reason = "cancelled"
-                if reason is not None:
-                    self._finish(r, reason)   # EOS/max_new on first token
-                    continue
-                slot = free.pop(0)
+                slot = free[0]
                 self.slots[slot] = r
                 self.adapter_slots[slot] = aidx[i]
+                if self._settle(slot, now):
+                    continue                # EOS/max_new on the first token
+                free.pop(0)
                 src.append(i)
                 dst.append(slot)
         if src:
-            src = np.asarray(src, np.int32)
-            dst = np.asarray(dst, np.int32)
-            self.cache = self._writer(self.cache, wave_cache, src, dst)
+            self.cache = self._writer(self.cache, wave_cache,
+                                      np.asarray(src, np.int32),
+                                      np.asarray(dst, np.int32))
             if self.speculate:
-                # the draft prefills the same wave (its logits are unused:
-                # the first token always comes from the target above), so
-                # seated slots start each spec round with draft KV covering
-                # exactly the target's positions. Same jitted fn — params
-                # are jit arguments, the draft's structure traces once.
-                with TraceAnnotation("engine.prefill", rows=w,
-                                     tokens=sum(lens), hit=0, prefix=0,
-                                     rids=_rids(group), kind="draft"):
-                    if self.registry is not None:
-                        _, dwave = fn(self.draft_params, jnp.asarray(toks),
-                                      jnp.asarray(lengths),
-                                      self.registry.stacked,
-                                      jnp.asarray(aidx))
-                    else:
-                        _, dwave = fn(self.draft_params, jnp.asarray(toks),
-                                      jnp.asarray(lengths))
-                self.draft_cache = self._writer(self.draft_cache, dwave,
-                                                src, dst)
+                self._draft_prefill([(slot, seqs[i])
+                                     for i, slot in zip(src, dst)])
         self.stats.prefill_waves += 1
 
     # -- paged prefill (block pool + prefix reuse) -----------------------------
@@ -1267,8 +1293,6 @@ class ServeEngine:
             quant_kv = self.cfg.quant_kv
             pool_leaves = self._pool_leaves
             n_suffix_blocks = padded_len // bs
-            lora = self.registry is not None
-            scaling = self.registry.scaling if lora else None
 
             def paged_prefill(cache, params, toks, lengths, prefix_len, pbt,
                               sbt, stacked=None, aidx=None):
@@ -1285,11 +1309,9 @@ class ServeEngine:
                         prefix["k_scale"] = gather("k_scale")
                         prefix["v_scale"] = gather("v_scale")
                     kw["prefix"] = prefix
-                if lora:
-                    kw.update(adapters=stacked, adapter_idx=aidx,
-                              lora_scaling=scaling)
-                logits, wave_cache = api.prefill(params, {"tokens": toks},
-                                                 wave, **kw)
+                logits, wave_cache = self._with_lora(api.prefill, stacked,
+                                                     aidx)(
+                    params, {"tokens": toks}, wave, **kw)
                 wave_cache = self._constrain_wave(wave_cache, toks.shape[0])
                 new_cache = dict(cache)
                 for name in pool_leaves:
@@ -1305,12 +1327,10 @@ class ServeEngine:
             self.stats.prefill_compiles += 1
         return self._prefill_cache[key]
 
-    def _prefill_group_paged(self, group: List[Request], free: List[int]):
-        """Admit one wave through the paged pool: match each sequence's
-        longest cached full-block prefix in the radix index, allocate
-        blocks for the un-cached suffix, prefill ONLY the suffix (rows
-        position-offset by their hit), and publish the sequence's full
-        blocks back into the index so later requests reuse them.
+    def _admit_paged(self, group: List[Request], free: List[int]):
+        """Plan a paged admission wave and run it: match each sequence's
+        longest cached full-block prefix in the radix index, cut the
+        un-cached rest to the step's budget, and take its blocks.
 
         Admission is atomic per request (``pager.admit``, plan-then-
         commit): a request the pool cannot hold — even after LRU
@@ -1318,152 +1338,171 @@ class ServeEngine:
         held, never half-admitted. Deferral, not preemption: blocks come
         back when a running slot finishes, and preempting here would
         thrash (the victim would immediately compete for the same
-        blocks). An exception during the prefill dispatch rolls every
-        admitted request's blocks back and requeues the wave."""
+        blocks)."""
         t_seat = self._now()
         pgr, bs = self.pager, self.kv_block_size
-        budgeted = self.prefill_budget is not None
-        admitted, slots_for = [], []    # slots are assigned up front: block
-        seqs, hits, hit_toks = [], [], []   # ownership needs a table
-        takes = []                      # suffix tokens consumed THIS wave
+        rows = []
         for r in group:
             seq = self._admission_seq(r)
             # LoRA requests bypass the prefix index: adapters targeting
             # wk/wv make the KV adapter-specific, so sharing it across
             # adapters (or with the base model) would be silently wrong
-            hit, ht = pgr.match(seq) if r.adapter is None else ([], 0)
-            take = len(seq) - ht
-            if budgeted:
+            hit, cursor = pgr.match(seq) if r.adapter is None else ([], 0)
+            take = len(seq) - cursor
+            if self.prefill_budget is not None:
                 take = prefill_chunk(take, self._prefill_left, bs)
                 if take == 0:
                     self.queue.push_front(r)   # step's budget spent
                     continue
-            slot = free[0]
-            if not pgr.admit(slot, hit, math.ceil(take / bs)):
+            # slots are assigned up front: block ownership needs a table
+            if not pgr.admit(free[0], hit, math.ceil(take / bs)):
                 self.queue.push_front(r)     # defer: pool dry right now
                 continue
-            free.pop(0)
-            if budgeted:
+            if self.prefill_budget is not None:
                 self._prefill_left -= take
-            admitted.append(r)
-            slots_for.append(slot)
-            seqs.append(seq)
-            hits.append(hit)
-            hit_toks.append(ht)
-            takes.append(take)
-        if not admitted:
+            rows.append(_Row(free.pop(0), r, seq, cursor, take))
+        self._paged_wave("first", rows, t_seat)
+
+    def _continue_prefill(self):
+        """Advance every mid-prefill slot by one budgeted chunk.
+
+        Runs at the top of admission, before new requests compete for
+        the step's prefill budget: in-flight prompts finish sooner,
+        which frees slots faster. Block allocation is all-or-nothing per
+        slot (``pager.extend``); when the pool cannot cover the wave's
+        plan, victims are preempted (mid-prefill slots included) until it
+        can or one slot remains, and a slot the pool still cannot extend
+        keeps its seat and defers its chunk to the next step."""
+        if self.prefill_budget is None:
             return
-        w = len(admitted)
+        pgr, bs = self.pager, self.kv_block_size
+        rows = []
+        for i, r in enumerate(self.slots):
+            if r is None or not r.prefilling:
+                continue
+            seq = self._admission_seq(r)
+            take = prefill_chunk(len(seq) - r.prefill_cursor,
+                                 self._prefill_left, bs)
+            if take == 0:
+                continue                # step budget exhausted
+            self._prefill_left -= take
+            rows.append(_Row(i, r, seq, r.prefill_cursor, take))
+        if not rows:
+            return
+        t0 = time.perf_counter()
+        try:
+            while not pgr.can_allocate(
+                    sum(math.ceil(row.take / bs) for row in rows)):
+                if sum(s is not None for s in self.slots) <= 1:
+                    break               # per-slot extend() defers below
+                victim = pick_victim(self.slots)
+                self._preempt_slot(victim)
+                rows = [row for row in rows if row.slot != victim]
+                if not rows:
+                    return
+            self._paged_wave("continue", [
+                row for row in rows
+                if pgr.extend(row.slot, math.ceil(row.take / bs))])
+        finally:
+            self.stats.prefill_wall_s += time.perf_counter() - t0
+
+    def _paged_wave(self, kind: str, rows: List[_Row], t_seat: float = 0.0):
+        """One paged prefill wave over planned rows whose blocks the plan
+        already took. For ``kind="first"`` a row's prefix blocks are a
+        prefix hit and its request is being seated (at ``t_seat``); for
+        ``"continue"`` they are the seated slot's own consumed blocks.
+
+        Every wave draws its shape from one lattice — pow2 width up to
+        ``n_slots``, pow2 chunk length capped by the budget, pow2 prefix
+        blocks — so the compile count is bounded whatever the arrival
+        pattern, without padding a lone chunk to the full slot set.
+
+        A fault during the dispatch rolls every row back: a new row's
+        blocks return to the pool and the request (pin intact) requeues;
+        a seated row truncates to its cursor, so a retried step re-plans
+        the same chunk. After the dispatch each row advances its
+        block-aligned cursor and publishes the consumed blocks; a row
+        whose prompt ended takes the first token and settles, and a row
+        still mid-prompt discards its logits (greedy output stays
+        bit-identical to an unbudgeted prefill)."""
+        if not rows:
+            return
+        pgr, bs = self.pager, self.kv_block_size
+        new = kind == "first"
         max_ctx = self.max_blocks * bs
-        wb = _pow2_bucket(w, 1, self.n_slots)
-        if budgeted:
-            # chunk length is bounded by the budget, so (wb, pl) comes
-            # from a small fixed lattice — O(log slots x log budget)
-            # compiles (first chunks here, continuations in
-            # _continue_prefill) regardless of arrival pattern, without
-            # padding a lone chunk to the full slot set
-            pl = _pow2_bucket(max(takes), bs,
-                              min(max_ctx, _pow2_bucket(
-                                  self.prefill_budget, bs, max_ctx)))
-        else:
-            pl = _pow2_bucket(max(takes), bs, max_ctx)
-        npb_max = max((len(h) for h in hits), default=0)
-        npb = _pow2_bucket(npb_max, 1, self.max_blocks) if npb_max else 0
+        wb = _pow2_bucket(len(rows), 1, self.n_slots)
+        pl = _pow2_bucket(max(row.take for row in rows), bs, _pow2_bucket(
+            self.prefill_budget or max_ctx, bs, max_ctx))
+        nh_max = max(row.cursor for row in rows) // bs
+        npb = _pow2_bucket(nh_max, 1, self.max_blocks) if nh_max else 0
         toks = np.zeros((wb, pl), np.int32)
         lengths = np.ones((wb,), np.int32)
         prefix_len = np.zeros((wb,), np.int32)
         pbt = np.zeros((wb, max(npb, 1)), np.int32)
         sbt = np.zeros((wb, pl // bs), np.int32)
         aidx = np.full((wb,), -1, np.int32)
-        for i, (r, slot) in enumerate(zip(admitted, slots_for)):
-            chunk = seqs[i][hit_toks[i]: hit_toks[i] + takes[i]]
-            toks[i, : len(chunk)] = chunk
-            lengths[i] = len(chunk)
-            prefix_len[i] = hit_toks[i]
-            nh = len(hits[i])
-            pbt[i, :nh] = hits[i]
-            nsb = math.ceil(len(chunk) / bs)
-            sbt[i, :nsb] = pgr.tables[slot, nh: nh + nsb]
-            if r.adapter is not None:
-                aidx[i] = self.registry.index_of(r.adapter)
+        for j, (slot, r, seq, cursor, take) in enumerate(rows):
+            nh, nsb = cursor // bs, math.ceil(take / bs)
+            toks[j, :take] = seq[cursor: cursor + take]
+            lengths[j] = take
+            prefix_len[j] = cursor
+            pbt[j, :nh] = pgr.tables[slot, :nh]
+            sbt[j, :nsb] = pgr.tables[slot, nh: nh + nsb]
+            aidx[j] = self._adapter_row(r)
         fn = self._get_paged_prefill(wb, pl, npb)
+        cached = sum(row.cursor for row in rows)
         try:
-            with TraceAnnotation("engine.prefill", rows=w, tokens=sum(takes),
-                                 hit=sum(hit_toks), prefix=sum(hit_toks),
-                                 rids=_rids(admitted), kind="first"):
-                logits, self.cache = self._dispatch_paged_prefill(
-                    fn, toks, lengths, prefix_len, pbt, sbt, aidx)
+            with TraceAnnotation("engine.prefill", rows=len(rows),
+                                 tokens=sum(row.take for row in rows),
+                                 hit=cached if new else 0, prefix=cached,
+                                 rids=_rids(row.req for row in rows),
+                                 kind=kind):
+                if self.fault_hook is not None:
+                    self.fault_hook("prefill")
+                logits, self.cache = fn(
+                    self.cache, self.params, jnp.asarray(toks),
+                    jnp.asarray(lengths), jnp.asarray(prefix_len),
+                    jnp.asarray(pbt), jnp.asarray(sbt),
+                    *self._lora_args(aidx))
         except Exception:
-            # roll the wave back: every admitted request's blocks return
-            # to the pool and the requests (pins intact) requeue
-            for r, slot in zip(admitted, slots_for):
-                pgr.release_slot(slot)
-                self.queue.push_front(r)
+            for slot, r, _, cursor, _ in rows:
+                if new:
+                    pgr.release_slot(slot)
+                    self.queue.push_front(r)
+                else:
+                    pgr.truncate(slot, cursor)
             self.stats.blocks_in_use = pgr.blocks_in_use
             raise
         first = self._sample(logits)
         now = self._now()
         with TraceAnnotation("engine.emit"):
-            for i, (r, slot) in enumerate(zip(admitted, slots_for)):
-                self._count_admission(r, t_seat)
-                r._swap = None
-                self.stats.prefill_tokens += int(lengths[i])
-                self.stats.prefix_hit_tokens += hit_toks[i]
-                if budgeted:
+            for j, (slot, r, seq, cursor, take) in enumerate(rows):
+                if new:
+                    self._count_admission(r, t_seat)
+                    r._swap = None
+                    self.stats.prefix_hit_tokens += cursor
+                    self.slots[slot] = r
+                    self.adapter_slots[slot] = aidx[j]
+                self.stats.prefill_tokens += take
+                if self.prefill_budget is not None:
                     self.stats.prefill_chunks += 1
                     self._prefill_progress = True
-                if hit_toks[i] + takes[i] < len(seqs[i]):
-                    # partial first chunk: the slot seats mid-prefill with a
-                    # block-aligned cursor and NO token (the chunk's last-
-                    # position logits are mid-prompt and discarded — greedy
-                    # output stays bit-identical to an unbudgeted prefill).
-                    # Publish the consumed whole blocks now so concurrent
-                    # requests (and a preemption/restore) reuse them.
-                    r.prefilling = True
-                    r.prefill_cursor = hit_toks[i] + takes[i]
-                    if r.adapter is None:
-                        pgr.insert(seqs[i][:r.prefill_cursor],
-                                   pgr.slot_blocks(slot))
-                    self.slots[slot] = r
-                    self.adapter_slots[slot] = aidx[i]
-                    continue
-                r.tokens.append(int(first[i]))
-                r.prefilling = False
-                r.prefill_cursor = len(seqs[i])
-                r.t_last = now
-                # publish the sequence's full blocks now: requests in later
-                # waves reuse this prefill while the slot is still decoding
-                # (base model only — LoRA KV is adapter-specific, see above)
+                r.prefill_cursor = cursor + take
+                r.prefilling = r.prefill_cursor < len(seq)
+                if not r.prefilling:
+                    r.tokens.append(int(first[j]))
+                    if self._settle(slot, now):
+                        continue        # its teardown published the blocks
+                # publish the consumed whole blocks now: later waves, and a
+                # preempted slot's re-admission, reuse them while this slot
+                # still runs (base model only: LoRA KV is adapter-specific)
                 if r.adapter is None:
-                    pgr.insert(seqs[i], pgr.slot_blocks(slot))
-                want_cancel = self._emit(r, now)
-                reason = self._stop_reason(r)
-                if reason is None and want_cancel:
-                    reason = "cancelled"
-                if reason is not None:
-                    pgr.release_slot(slot)
-                    self._finish(r, reason)   # EOS/max_new on the first token
-                    free.append(slot)         # reusable by the next group
-                    continue
-                self.slots[slot] = r
-                self.adapter_slots[slot] = aidx[i]
+                    pgr.insert(seq[:r.prefill_cursor], pgr.slot_blocks(slot))
         if self.speculate:
-            self._draft_prefill_paged(admitted, slots_for, seqs)
+            self._draft_prefill([(slot, seq) for slot, r, seq, _, _ in rows
+                                 if self.slots[slot] is r])
         self.stats.prefill_waves += 1
         self.stats.blocks_in_use = pgr.blocks_in_use
-
-    def _dispatch_paged_prefill(self, fn, toks, lengths, prefix_len, pbt,
-                                sbt, aidx):
-        """Upload one paged wave's host arrays and dispatch its program
-        (pool donated); the fault hook fires first."""
-        args = [self.cache, self.params, jnp.asarray(toks),
-                jnp.asarray(lengths), jnp.asarray(prefix_len),
-                jnp.asarray(pbt), jnp.asarray(sbt)]
-        if self.registry is not None:
-            args += [self.registry.stacked, jnp.asarray(aidx)]
-        if self.fault_hook is not None:
-            self.fault_hook("prefill")
-        return fn(*args)
 
     def _count_admission(self, r: Request, t_seat: float):
         """Admission bookkeeping of a prefilled row: a request seated for
@@ -1480,174 +1519,37 @@ class ServeEngine:
         if r.adapter is not None:
             self.stats.lora_requests += 1
 
-    def _continue_prefill(self):
-        """Advance every mid-prefill slot by one budgeted chunk.
-
-        Runs at the top of admission, before new requests compete for
-        the step's prefill budget: in-flight prompts finish sooner,
-        which frees slots faster. All continuations batch into ONE wave
-        through the same jitted paged-prefill bucket the first chunks
-        use (prefix = the slot's own consumed blocks, suffix = the next
-        chunk), so arrival patterns never grow the compile space."""
-        if self.prefill_budget is None:
+    def _draft_prefill(self, seated):
+        """Draft-side prefill of the ``(slot, seq)`` rows an admission wave
+        seated, after the target wave committed. The draft cache is dense,
+        so the FULL sequence of each row prefills through the dense path
+        (a paged prefix hit saves target compute only; the draft is the
+        cheap model by construction) and each speculative round starts
+        with draft KV covering exactly the target's positions. Its logits
+        are unused: the first token always comes from the target."""
+        if not seated:
             return
-        bs = self.kv_block_size
-        items = []                      # (slot, request, seq, take)
-        for i, r in enumerate(self.slots):
-            if r is None or not r.prefilling:
-                continue
-            seq = self._admission_seq(r)
-            take = prefill_chunk(len(seq) - r.prefill_cursor,
-                                 self._prefill_left, bs)
-            if take == 0:
-                continue                # step budget exhausted
-            self._prefill_left -= take
-            items.append((i, r, seq, take))
-        if not items:
-            return
-        t0 = time.perf_counter()
-        try:
-            self._continue_prefill_wave(items)
-        finally:
-            self.stats.prefill_wall_s += time.perf_counter() - t0
-
-    def _continue_prefill_wave(self, items):
-        """One continuation wave. Block allocation is all-or-nothing per
-        slot (``pager.extend``); when the pool cannot cover the wave's
-        plan, victims are preempted (mid-prefill slots included) until it
-        can or one slot remains. A fault during the dispatch rolls the
-        extension back to the cursor (``pager.truncate``) — the slots
-        stay seated and a retried step re-runs the identical chunk."""
-        pgr, bs = self.pager, self.kv_block_size
-        while True:
-            need = sum(math.ceil(take / bs) for _, _, _, take in items)
-            if pgr.can_allocate(need):
-                break
-            if sum(s is not None for s in self.slots) <= 1:
-                break                   # per-slot extend() defers below
-            victim = pick_victim(self.slots)
-            self._preempt_slot(victim)
-            items = [it for it in items if it[0] != victim]
-            if not items:
-                return
-        ran = []
-        for slot, r, seq, take in items:
-            if pgr.extend(slot, math.ceil(take / bs)):
-                ran.append((slot, r, seq, take))
-            # else: chunk deferred to the next step, slot stays seated
-        if not ran:
-            return
-        max_ctx = self.max_blocks * bs
-        # same bucket lattice as budgeted admission waves: pow2 width,
-        # pow2 length capped by the budget
-        wb = _pow2_bucket(len(ran), 1, self.n_slots)
-        pl = _pow2_bucket(max(t for *_, t in ran), bs,
-                          min(max_ctx, _pow2_bucket(self.prefill_budget,
-                                                    bs, max_ctx)))
-        npb = _pow2_bucket(max(r.prefill_cursor // bs
-                               for _, r, _, _ in ran), 1, self.max_blocks)
-        toks = np.zeros((wb, pl), np.int32)
-        lengths = np.ones((wb,), np.int32)
-        prefix_len = np.zeros((wb,), np.int32)
-        pbt = np.zeros((wb, npb), np.int32)
-        sbt = np.zeros((wb, pl // bs), np.int32)
-        aidx = np.full((wb,), -1, np.int32)
-        for j, (slot, r, seq, take) in enumerate(ran):
-            cur = r.prefill_cursor      # block-aligned by construction
-            toks[j, :take] = seq[cur: cur + take]
-            lengths[j] = take
-            prefix_len[j] = cur
-            nh = cur // bs
-            pbt[j, :nh] = pgr.tables[slot, :nh]
-            nsb = math.ceil(take / bs)
-            sbt[j, :nsb] = pgr.tables[slot, nh: nh + nsb]
-            aidx[j] = self.adapter_slots[slot]
-        fn = self._get_paged_prefill(wb, pl, npb)
-        try:
-            with TraceAnnotation("engine.prefill", rows=len(ran),
-                                 tokens=sum(t for *_, t in ran), hit=0,
-                                 prefix=int(prefix_len.sum()),
-                                 rids=_rids(r for _, r, _, _ in ran),
-                                 kind="continue"):
-                logits, self.cache = self._dispatch_paged_prefill(
-                    fn, toks, lengths, prefix_len, pbt, sbt, aidx)
-        except Exception:
-            # roll the extension back to the cursor: the slots stay
-            # seated mid-prefill and a retried step re-plans the exact
-            # same chunk (deterministic, so retry is token-identical)
-            for slot, r, _, _ in ran:
-                pgr.truncate(slot, r.prefill_cursor)
-            self.stats.blocks_in_use = pgr.blocks_in_use
-            raise
-        first = self._sample(logits)
-        now = self._now()
-        with TraceAnnotation("engine.emit"):
-            for j, (slot, r, seq, take) in enumerate(ran):
-                r.prefill_cursor += take
-                self.stats.prefill_tokens += take
-                self.stats.prefill_chunks += 1
-                self._prefill_progress = True
-                if r.adapter is None:
-                    pgr.insert(seq[:r.prefill_cursor], pgr.slot_blocks(slot))
-                if r.prefill_cursor < len(seq):
-                    continue                # still mid-prompt
-                # final chunk: the wave's last-position logits are the real
-                # end-of-prompt logits — sample the first token and hand the
-                # slot to decode
-                r.prefilling = False
-                r.tokens.append(int(first[j]))
-                r.t_last = now
-                want_cancel = self._emit(r, now)
-                reason = self._stop_reason(r)
-                if reason is None and want_cancel:
-                    reason = "cancelled"
-                if reason is not None:
-                    pgr.release_slot(slot)
-                    self._finish(r, reason)
-                    self.slots[slot] = None
-                    self.adapter_slots[slot] = -1
-        self.stats.prefill_waves += 1
-        self.stats.blocks_in_use = pgr.blocks_in_use
-
-    def _draft_prefill_paged(self, admitted, slots_for, seqs):
-        """Draft-side prefill for a paged admission wave: the draft cache
-        is dense, so it cannot ride the suffix-only paged dispatch —
-        instead the FULL sequence of every seated request prefills through
-        the plain dense path (prefix hits save target compute only; the
-        draft recomputes its whole KV, which is the cheap model by
-        construction). Runs after the target wave committed: a request the
-        target deferred or finished at prefill never reaches here."""
-        keep = [(i, slot) for i, (r, slot) in enumerate(zip(admitted,
-                                                            slots_for))
-                if self.slots[slot] is r]
-        if not keep:
-            return
-        wb = _pow2_bucket(len(keep), 1, self.n_slots)
-        pl = _pow2_bucket(max(len(seqs[i]) for i, _ in keep),
+        wb = _pow2_bucket(len(seated), 1, self.n_slots)
+        pl = _pow2_bucket(max(len(seq) for _, seq in seated),
                           min(8, self.max_len), self.max_len)
         toks = np.zeros((wb, pl), np.int32)
         lengths = np.ones((wb,), np.int32)
         aidx = np.full((wb,), -1, np.int32)
-        for j, (i, slot) in enumerate(keep):
-            toks[j, : len(seqs[i])] = seqs[i]
-            lengths[j] = len(seqs[i])
+        for j, (slot, seq) in enumerate(seated):
+            toks[j, : len(seq)] = seq
+            lengths[j] = len(seq)
             aidx[j] = self.adapter_slots[slot]
         fn = self._get_prefill(wb, pl)
-        with TraceAnnotation("engine.prefill", rows=len(keep),
-                             tokens=int(lengths[:len(keep)].sum()), hit=0,
+        with TraceAnnotation("engine.prefill", rows=len(seated),
+                             tokens=int(lengths[:len(seated)].sum()), hit=0,
                              prefix=0,
-                             rids=_rids(admitted[i] for i, _ in keep),
+                             rids=_rids(self.slots[s] for s, _ in seated),
                              kind="draft"):
-            if self.registry is not None:
-                _, dwave = fn(self.draft_params, jnp.asarray(toks),
-                              jnp.asarray(lengths), self.registry.stacked,
-                              jnp.asarray(aidx))
-            else:
-                _, dwave = fn(self.draft_params, jnp.asarray(toks),
-                              jnp.asarray(lengths))
-        src = np.arange(len(keep), dtype=np.int32)
-        dst = np.asarray([slot for _, slot in keep], np.int32)
-        self.draft_cache = self._writer(self.draft_cache, dwave, src, dst)
+            _, dwave = fn(self.draft_params, jnp.asarray(toks),
+                          jnp.asarray(lengths), *self._lora_args(aidx))
+        self.draft_cache = self._writer(
+            self.draft_cache, dwave, np.arange(len(seated), dtype=np.int32),
+            np.asarray([slot for slot, _ in seated], np.int32))
 
     # -- sampling --------------------------------------------------------------
     def _sample(self, logits):
@@ -1697,43 +1599,25 @@ class ServeEngine:
 
     # -- decode ----------------------------------------------------------------
     def _get_chunk_fn(self, n: int):
-        """Jitted scan-decode for chunk length n (cache donated).
-
-        With an adapter registry the callable takes the stacked A/B pytree
-        and the per-slot [B] adapter-index row as leading jit arguments
-        (so registry hot-swaps reuse the compile cache) and the wrapped
-        ``api.decode`` runs the gathered LoRA delta pipeline alongside the
-        untouched base path every scan step."""
+        """Jitted scan-decode for chunk length n (cache donated). With an
+        adapter registry the trailing ``stacked, aidx`` arguments (the
+        stacked A/B pytree and the per-slot [B] adapter-index row) run
+        the gathered LoRA delta pipeline alongside the untouched base
+        path every scan step."""
         key = (n, self.greedy)
         if key not in self._chunk_fns:
             api, cfg = self.api, self.cfg
             eos_id, max_len, greedy = self.eos_id, self.max_len, self.greedy
-            if self.registry is None:
-                def decode_chunk(params, last, cache, rng, stop, gen,
-                                 max_new):
-                    return decode_steps(
-                        api.decode, params, last, cache, rng, stop, gen,
-                        max_new, n=n, vocab_size=cfg.vocab_size,
-                        max_len=max_len, eos_id=eos_id, greedy=greedy)
 
-                self._chunk_fns[key] = jax.jit(decode_chunk,
-                                               donate_argnums=(2,))
-            else:
-                scaling = self.registry.scaling
+            def decode_chunk(params, last, cache, rng, stop, gen, max_new,
+                             stacked=None, aidx=None):
+                return decode_steps(
+                    self._with_lora(api.decode, stacked, aidx), params,
+                    last, cache, rng, stop, gen, max_new, n=n,
+                    vocab_size=cfg.vocab_size, max_len=max_len,
+                    eos_id=eos_id, greedy=greedy)
 
-                def decode_chunk(params, stacked, aidx, last, cache, rng,
-                                 stop, gen, max_new):
-                    def dec(p, t, c):
-                        return api.decode(p, t, c, adapters=stacked,
-                                          adapter_idx=aidx,
-                                          lora_scaling=scaling)
-                    return decode_steps(
-                        dec, params, last, cache, rng, stop, gen,
-                        max_new, n=n, vocab_size=cfg.vocab_size,
-                        max_len=max_len, eos_id=eos_id, greedy=greedy)
-
-                self._chunk_fns[key] = jax.jit(decode_chunk,
-                                               donate_argnums=(4,))
+            self._chunk_fns[key] = jax.jit(decode_chunk, donate_argnums=(2,))
         return self._chunk_fns[key]
 
     # -- speculative decode ----------------------------------------------------
@@ -1755,51 +1639,25 @@ class ServeEngine:
             api, cfg = self.api, self.cfg
             vs = cfg.vocab_size
             no_stop_len = self.max_len + 2    # pos bound can never fire
-            if self.registry is None:
-                def spec_draft(dparams, last, dcache, rng, stop):
-                    b = last.shape[0]
-                    return decode_steps(
-                        api.decode, dparams, last, dcache, rng, stop,
-                        jnp.zeros((b,), jnp.int32),
-                        jnp.full((b,), 1 << 30, jnp.int32),
-                        n=k + 1, vocab_size=vs, max_len=no_stop_len,
-                        eos_id=None, greedy=True)
 
-                def spec_verify(params, last, drafts, cache):
-                    return verify_steps(api.decode, params, last, drafts,
-                                        cache, vocab_size=vs)
+            def spec_draft(dparams, last, dcache, rng, stop, stacked=None,
+                           aidx=None):
+                b = last.shape[0]
+                return decode_steps(
+                    self._with_lora(api.decode, stacked, aidx), dparams,
+                    last, dcache, rng, stop, jnp.zeros((b,), jnp.int32),
+                    jnp.full((b,), 1 << 30, jnp.int32),
+                    n=k + 1, vocab_size=vs, max_len=no_stop_len,
+                    eos_id=None, greedy=True)
 
-                self._spec_fns[k] = (
-                    jax.jit(spec_draft, donate_argnums=(2,)),
-                    jax.jit(spec_verify, donate_argnums=(3,)))
-            else:
-                scaling = self.registry.scaling
+            def spec_verify(params, last, drafts, cache, stacked=None,
+                            aidx=None):
+                return verify_steps(
+                    self._with_lora(api.decode, stacked, aidx), params,
+                    last, drafts, cache, vocab_size=vs)
 
-                def spec_draft(dparams, stacked, aidx, last, dcache, rng,
-                               stop):
-                    def dec(p, t, c):
-                        return api.decode(p, t, c, adapters=stacked,
-                                          adapter_idx=aidx,
-                                          lora_scaling=scaling)
-                    b = last.shape[0]
-                    return decode_steps(
-                        dec, dparams, last, dcache, rng, stop,
-                        jnp.zeros((b,), jnp.int32),
-                        jnp.full((b,), 1 << 30, jnp.int32),
-                        n=k + 1, vocab_size=vs, max_len=no_stop_len,
-                        eos_id=None, greedy=True)
-
-                def spec_verify(params, stacked, aidx, last, drafts, cache):
-                    def dec(p, t, c):
-                        return api.decode(p, t, c, adapters=stacked,
-                                          adapter_idx=aidx,
-                                          lora_scaling=scaling)
-                    return verify_steps(dec, params, last, drafts, cache,
-                                        vocab_size=vs)
-
-                self._spec_fns[k] = (
-                    jax.jit(spec_draft, donate_argnums=(4,)),
-                    jax.jit(spec_verify, donate_argnums=(5,)))
+            self._spec_fns[k] = (jax.jit(spec_draft, donate_argnums=(2,)),
+                                 jax.jit(spec_verify, donate_argnums=(3,)))
         return self._spec_fns[k]
 
     def _spec_step(self, active, max_n: Optional[int]) -> bool:
@@ -1888,15 +1746,10 @@ class ServeEngine:
                                  rows=len(active), keys=keys, rids=rids):
                 if self.fault_hook is not None:
                     self.fault_hook("draft")
-                if self.registry is not None:
-                    dout = draft_fn(self.draft_params, self.registry.stacked,
-                                    jnp.asarray(self.adapter_slots),
-                                    jnp.asarray(last), self.draft_cache,
-                                    self.rng, jnp.asarray(stop))
-                else:
-                    dout = draft_fn(self.draft_params, jnp.asarray(last),
-                                    self.draft_cache, self.rng,
-                                    jnp.asarray(stop))
+                dout = draft_fn(self.draft_params, jnp.asarray(last),
+                                self.draft_cache, self.rng,
+                                jnp.asarray(stop),
+                                *self._lora_args(self.adapter_slots))
             self.draft_cache = dout.cache
             with TraceAnnotation("engine.sync", what="draft"):
                 drafts = np.asarray(dout.tokens)[:k]
@@ -1906,15 +1759,9 @@ class ServeEngine:
                              rows=len(active), keys=keys, rids=rids):
             if self.fault_hook is not None:
                 self.fault_hook("verify")
-            if self.registry is not None:
-                targets_dev, self.cache = verify_fn(
-                    self.params, self.registry.stacked,
-                    jnp.asarray(self.adapter_slots), jnp.asarray(last),
-                    jnp.asarray(drafts), self.cache)
-            else:
-                targets_dev, self.cache = verify_fn(
-                    self.params, jnp.asarray(last), jnp.asarray(drafts),
-                    self.cache)
+            targets_dev, self.cache = verify_fn(
+                self.params, jnp.asarray(last), jnp.asarray(drafts),
+                self.cache, *self._lora_args(self.adapter_slots))
         with TraceAnnotation("engine.sync", what="verify"):
             targets = np.asarray(targets_dev)          # [k+1, B]
         now = self._now()
@@ -1924,16 +1771,13 @@ class ServeEngine:
                 r = self.slots[i]
                 m = accept_length(drafts[:, i], targets[:, i])
                 got = 0
-                reason = None
                 for t in range(m + 1):
                     # stops are re-derived per appended token: an EOS / budget
                     # / cache-full landing mid-acceptance discards the rest
                     r.tokens.append(int(targets[t, i]))
                     got += 1
-                    reason = self._stop_reason(r)
-                    if reason is not None:
+                    if self._stop_reason(r) is not None:
                         break
-                r.t_last = now
                 emitted += got
                 self.stats.spec_slot_rounds += 1
                 self.stats.drafted_tokens += k
@@ -1945,18 +1789,7 @@ class ServeEngine:
                     # (prompt ++ tokens[:-1]); whole tail blocks written for
                     # rejected positions return to the pool
                     self.pager.truncate(i, positions[i] + got)
-                want_cancel = self._emit(r, now)
-                if reason is None and want_cancel:
-                    reason = "cancelled"
-                if reason is not None:
-                    if self.paged:
-                        if r.adapter is None:
-                            self.pager.insert(self._kv_seq(r),
-                                              self.pager.slot_blocks(i))
-                        self.pager.release_slot(i)
-                    self._finish(r, reason)
-                    self.slots[i] = None
-                    self.adapter_slots[i] = -1
+                self._settle(i, now)
         self.stats.spec_rounds += 1
         self.stats.spec_emitted_tokens += emitted
         self.stats.decode_tokens += emitted
@@ -2135,15 +1968,9 @@ class ServeEngine:
                 # after the (idempotent) pager commit, before the dispatch:
                 # a fault here leaves the step cleanly retryable
                 self.fault_hook("decode")
-            if self.registry is not None:
-                out = fn(self.params, self.registry.stacked,
-                         jnp.asarray(self.adapter_slots), jnp.asarray(last),
-                         self.cache, self.rng, jnp.asarray(stop),
-                         jnp.asarray(gen), jnp.asarray(budget))
-            else:
-                out = fn(self.params, jnp.asarray(last), self.cache,
-                         self.rng, jnp.asarray(stop), jnp.asarray(gen),
-                         jnp.asarray(budget))
+            out = fn(self.params, jnp.asarray(last), self.cache, self.rng,
+                     jnp.asarray(stop), jnp.asarray(gen), jnp.asarray(budget),
+                     *self._lora_args(self.adapter_slots))
         self.cache, self.rng = out.cache, out.rng
         with TraceAnnotation("engine.sync", what="decode"):
             toks = np.asarray(out.tokens)
@@ -2156,32 +1983,12 @@ class ServeEngine:
         with TraceAnnotation("engine.emit"):
             for i in decode_active:
                 r = self.slots[i]
-                got = 0
+                # a decoding row's first step is always valid: got >= 1
                 for t in range(n):
                     if not valid[t, i]:
                         break
                     r.tokens.append(int(toks[t, i]))
-                    got += 1
-                if got:
-                    r.t_last = now
-                want_cancel = self._emit(r, now)
-                reason = self._stop_reason(r)
-                if reason is None and want_cancel:
-                    reason = "cancelled"
-                if reason is not None:
-                    if self.paged:
-                        # publish the generated tokens' full blocks too (KV at
-                        # position p is keyed by prompt ++ tokens[:-1], the
-                        # sequence actually fed), then drop the slot's refs —
-                        # indexed blocks stay cached for future requests.
-                        # LoRA rows stay unindexed (adapter-specific KV).
-                        if r.adapter is None:
-                            self.pager.insert(self._kv_seq(r),
-                                              self.pager.slot_blocks(i))
-                        self.pager.release_slot(i)
-                    self._finish(r, reason)
-                    self.slots[i] = None
-                    self.adapter_slots[i] = -1
+                self._settle(i, now)
         if self.paged:
             self.stats.blocks_in_use = self.pager.blocks_in_use
         return True
@@ -2196,34 +2003,24 @@ class ServeEngine:
             max_steps -= self.stats.steps - before
         return self.finished
 
+    def _baked_settings(self) -> tuple:
+        """What the jitted closures bake in of this engine's settings."""
+        spec = self.speculate
+        return (self.cfg, self.eos_id, self.max_len, self.greedy,
+                self.n_slots, self.registry is None,
+                getattr(self.registry, "scaling", None), self.paged,
+                self.kv_block_size if self.paged else None,
+                getattr(self, "num_blocks", None) if self.paged else None,
+                self.mesh, spec, self.spec_k if spec else None,
+                self.draft_bits if spec else None,
+                self.draft_mode if spec else None, self.prefill_budget)
+
     def adopt_compiled(self, other: "ServeEngine"):
         """Inherit another engine's jitted callables (benchmark warmup:
         the timed engine starts compile-free). The adopted closures bake
         the source engine's config and stop semantics, so mismatched
         engines are rejected rather than silently decoding wrong tokens."""
-        mine = (self.cfg, self.eos_id, self.max_len, self.greedy,
-                self.n_slots, self.registry is None,
-                None if self.registry is None else self.registry.scaling,
-                self.paged,
-                self.kv_block_size if self.paged else None,
-                getattr(self, "num_blocks", None) if self.paged else None,
-                self.mesh,
-                self.speculate, self.spec_k if self.speculate else None,
-                self.draft_bits if self.speculate else None,
-                self.draft_mode if self.speculate else None,
-                self.prefill_budget)
-        theirs = (other.cfg, other.eos_id, other.max_len, other.greedy,
-                  other.n_slots, other.registry is None,
-                  None if other.registry is None else other.registry.scaling,
-                  other.paged,
-                  other.kv_block_size if other.paged else None,
-                  getattr(other, "num_blocks", None) if other.paged else None,
-                  other.mesh,
-                  other.speculate,
-                  other.spec_k if other.speculate else None,
-                  other.draft_bits if other.speculate else None,
-                  other.draft_mode if other.speculate else None,
-                  other.prefill_budget)
+        mine, theirs = self._baked_settings(), other._baked_settings()
         if mine != theirs:
             raise ValueError(
                 "adopt_compiled: engines differ in (cfg, eos_id, max_len, "

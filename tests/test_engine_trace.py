@@ -206,3 +206,9 @@ def test_decode_keys_and_prefill_prefix_on_a_session(params, tmp_path):
                for g in range(1, len(r.tokens)))
     assert sum(st["keys"] for st in decode) == want
     assert [len(r.tokens) for r in eng.finished] == [6, 5]
+    # one lattice for first chunks and continuations, (wave, chunk length,
+    # prefix blocks): the budget-cut first chunk, the 5-token continuation
+    # after 2 blocks of its own, the 13-token turn after a 3-block hit
+    assert set(eng._prefill_cache) == {("paged", 1, 16, 0),
+                                       ("paged", 1, 8, 2),
+                                       ("paged", 1, 16, 4)}
